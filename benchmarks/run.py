@@ -3,7 +3,9 @@
     PYTHONPATH=src python -m benchmarks.run [--quick] [--only NAME]
 
 Prints ``name,us_per_call,derived`` CSV to stdout (one line per benchmark
-row) and writes the full per-figure CSVs to experiments/bench/.
+row) and writes the full per-figure CSVs to experiments/bench/.  A
+benchmark that raises prints an ERROR row, the rest still run, and the
+process exits 1.
 """
 from __future__ import annotations
 
@@ -83,6 +85,7 @@ def main():
     reg("roofline", roofline_rows)
 
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in benches:
         t0 = time.time()
         try:
@@ -90,9 +93,15 @@ def main():
                 print(line, flush=True)
             print(f"# {name} done in {time.time()-t0:.0f}s",
                   file=sys.stderr)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report, run the rest
             print(f"{name},nan,ERROR:{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
+    if failed:
+        print(f"# failed: {', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -1,20 +1,16 @@
 """Public jit'd wrappers around the Pallas SQS kernels.
 
-``INTERPRET`` is tri-state: None (default) auto-detects the backend —
-kernels COMPILE on TPU and fall back to the Pallas interpreter on
-CPU/GPU, so the kernel path is no longer interpreter-only in production.
-Force either mode with ``repro.kernels.ops.INTERPRET = True/False`` or
-env REPRO_PALLAS_COMPILE=1 / REPRO_PALLAS_INTERPRET=1
-(``decode_attention.resolve_interpret``).
+The backend decides how a kernel runs, and nothing else does: kernels
+COMPILE everywhere except on the CPU backend, where the Pallas
+interpreter runs them (that is how the test suite exercises them).
 
-The wrappers handle vocab padding (lane multiple of 128, -inf logits) and
+The wrappers handle vocab padding (``sqs_fused.pad_vocab``, -inf logits) and
 adapt kernel outputs to the ``core.sqs.SQSResult`` interface, so the engine
 can swap jnp ↔ Pallas paths with one flag.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,13 +18,10 @@ import jax.numpy as jnp
 from repro.core.sqs import SQSResult
 from repro.kernels import ref as ref_mod
 from repro.kernels import sqs_fused as k
-from repro.kernels.decode_attention import resolve_interpret
-
-INTERPRET: Optional[bool] = None     # None = auto-detect backend
 
 
 def _interpret() -> bool:
-    return resolve_interpret(INTERPRET)
+    return jax.default_backend() == "cpu"
 
 
 def _pad_logits(logits):
@@ -47,35 +40,25 @@ def sqs_threshold(logits, beta, temperature: float = 1.0, ell: int = 100,
     """C-SQS edge step, fused:  softmax(T) → support {q ≥ β} → dropped
     mass → lattice counts with Σb = ℓ exact.  logits: (B, V); beta: (B,)."""
     lp, V = _pad_logits(logits)
-    beta2 = jnp.stack([beta, beta], axis=-1).astype(jnp.float32)
-    fn = ref_mod.sqs_fused_ref if use_ref else functools.partial(
-        k.sqs_fused_call, interpret=_interpret())
-    b, mask, stats = fn(lp, beta2, inv_temp=1.0 / max(temperature, 1e-4),
-                        ell=ell)
-    q_hat = (b[:, :V].astype(jnp.float32) / ell)
-    return SQSResult(q_hat, mask[:, :V].astype(bool), stats[:, 0],
-                     stats[:, 1].astype(jnp.int32))
+    return _sqs(lp, V, beta.astype(jnp.float32), temperature, ell, 0,
+                use_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("K", "temperature", "ell",
                                              "use_ref"))
 def sqs_topk(logits, K: int, temperature: float = 1.0, ell: int = 100,
              use_ref: bool = False) -> SQSResult:
-    """K-SQS edge step: bisection top-K threshold + fused quantizer."""
+    """K-SQS edge step: bisection top-K threshold + fused quantizer, both
+    on the kernel's own softmax."""
     lp, V = _pad_logits(logits)
-    it = 1.0 / max(temperature, 1e-4)
-    # probabilities for the threshold search (same math as the main kernel)
-    x = lp * it
-    m = jnp.max(x, axis=-1, keepdims=True)
-    q = jnp.exp(x - m) / jnp.sum(jnp.exp(x - m), axis=-1, keepdims=True)
-    if use_ref:
-        tau = ref_mod.topk_threshold_ref(q, K)
-        b, mask, stats = ref_mod.sqs_fused_ref(lp, tau, inv_temp=it,
-                                               ell=ell, exact_k=K)
-    else:
-        tau = k.topk_threshold_call(q, K, interpret=_interpret())
-        b, mask, stats = k.sqs_fused_call(lp, tau, inv_temp=it, ell=ell,
-                                          exact_k=K, interpret=_interpret())
+    return _sqs(lp, V, None, temperature, ell, K, use_ref)
+
+
+def _sqs(lp, V, beta, temperature, ell, exact_k, use_ref) -> SQSResult:
+    fn = ref_mod.sqs_fused_ref if use_ref else functools.partial(
+        k.sqs_fused_call, interpret=_interpret())
+    b, mask, stats = fn(lp, beta, inv_temp=1.0 / max(temperature, 1e-4),
+                        ell=ell, exact_k=exact_k)
     q_hat = (b[:, :V].astype(jnp.float32) / ell)
     return SQSResult(q_hat, mask[:, :V].astype(bool), stats[:, 0],
                      stats[:, 1].astype(jnp.int32))

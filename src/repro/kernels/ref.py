@@ -10,27 +10,39 @@ import jax
 import jax.numpy as jnp
 
 
+def _row_sum(x):
+    """Row sums reduced one (Vp/128, 128) row tile at a time, the way
+    the kernel reduces them — a batched reduction may add in another
+    order and differ in the last bits (Vp must be a multiple of 128)."""
+    B, Vp = x.shape
+    t = x.reshape(B, Vp // 128, 128)
+    return jax.lax.map(
+        lambda r: jnp.sum(jnp.sum(r, axis=-1, keepdims=True), axis=-2),
+        t)
+
+
 def sqs_fused_ref(logits_padded, beta, *, inv_temp: float, ell: int,
                   exact_k: int = 0):
     """Mirror of kernels.sqs_fused._sqs_kernel over the whole batch.
-    logits_padded: (B, Vp) f32 (-inf padded); beta: (B, 2) f32 [lo, hi].
+    logits_padded: (B, Vp) f32 (-inf padded, Vp a multiple of 128);
+    beta: (B,) f32 C-SQS thresholds (None for K-SQS, exact_k > 0).
     Returns (b (B,Vp) i32, mask (B,Vp) i32, stats (B,4) f32)."""
     x = logits_padded.astype(jnp.float32) * inv_temp
     m = jnp.max(x, axis=-1, keepdims=True)
     e = jnp.exp(x - m)
-    s = jnp.sum(e, axis=-1, keepdims=True)
+    s = _row_sum(e)
     q = e / s
 
     if exact_k > 0:
-        lo = beta[:, 0:1]
+        lo = topk_threshold_ref(q, exact_k)[:, 0:1]
         cand = q >= lo
         csum = jnp.cumsum(cand.astype(jnp.float32), axis=-1)
         mask = cand & (csum <= exact_k)
     else:
         is_max = x >= m
-        mask = (q >= beta[:, 0:1]) | is_max
+        mask = (q >= beta[:, None]) | is_max
     qm = jnp.where(mask, q, 0.0)
-    sm = jnp.sum(qm, axis=-1, keepdims=True)
+    sm = _row_sum(qm)
     K = jnp.sum(mask.astype(jnp.float32), axis=-1, keepdims=True)
     dropped = 1.0 - sm
 

@@ -176,8 +176,6 @@ def calibrate_combo(arch: str, shape_name: str, multi_pod: bool,
             with mesh:
                 lowered = build_lowered(cfg_r, shape, mesh, axes, fsdp)
             ca = lowered.compile().cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
             cal[f"cost_{n_units}p"] = {
                 k: ca[k] for k in ("flops", "bytes accessed") if k in ca}
         rec["scan_calibration"] = cal
@@ -264,8 +262,6 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool,
                                 - ma.alias_size_in_bytes),
         }
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):     # jax<=0.4: per-device list
-            ca = ca[0] if ca else {}
         rec["cost"] = {k: ca[k] for k in ("flops", "bytes accessed")
                        if k in ca}
         txt = compiled.as_text()

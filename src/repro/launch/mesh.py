@@ -12,19 +12,14 @@ import jax
 from repro.sharding.partition import MeshAxes
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` appeared in jax 0.5 (jax.sharding.AxisType); older
-    releases default every axis to Auto anyway — pass nothing there."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is None:
-        return {}
-    return {"axis_types": (at.Auto,) * n_axes}
+def _auto_axes(n_axes: int) -> dict:
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, **_auto_axes(len(axes)))
 
 
 def mesh_axes(*, multi_pod: bool = False) -> MeshAxes:
@@ -34,7 +29,7 @@ def mesh_axes(*, multi_pod: bool = False) -> MeshAxes:
 def make_debug_mesh(n_data: int = 2, n_model: int = 4):
     """Small host-device mesh for sharding tests."""
     return jax.make_mesh((n_data, n_model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         **_auto_axes(2))
 
 
 # TPU v5e hardware constants (roofline targets; DESIGN.md §3)

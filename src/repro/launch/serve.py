@@ -108,7 +108,7 @@ def finish_obs(args, obs: Obs, tcp: bool):
           "present, rejection telemetry reconciles with thm1_terms")
 
 
-def run_tcp_vs_sim(args, tc, dc, dp, sim_rep, cache_len, obs=None):
+def run_tcp_vs_sim(args, tc, dc, dp, sim_rep, obs=None):
     """Replay the SAME seeded trace over real sockets, with the
     simulated run as differential oracle: token streams must be
     bit-identical (the transport moves bytes, never tokens), while the
@@ -118,24 +118,9 @@ def run_tcp_vs_sim(args, tc, dc, dp, sim_rep, cache_len, obs=None):
 
     assert args.page_size == 0, \
         "--transport tcp serves dense slots only"
-    method = MethodConfig(args.method, K=args.K, ell=args.ell,
-                          alpha=args.alpha, eta=args.eta)
-    ecfg = EngineConfig(L_max=args.L_max, bit_budget=args.bit_budget,
-                        temperature=args.temperature,
-                        wire_codec=args.wire_codec,
-                        budget_model=args.budget_model)
-    cfg = ServeConfig(
-        max_batch=args.max_batch, queue_cap=args.queue_cap,
-        policy=args.policy, cache_len=cache_len,
-        pipeline=args.pipeline, speculate=not args.no_speculate,
-        n_cells=args.cells, verdict_batch=args.verdict_batch)
     # a fresh trace: Request objects are mutated by a run, and the
     # generator is fully determined by its seeded config
-    trace = poisson_trace(TraceConfig(
-        n_requests=args.n_requests, rate_rps=args.rate,
-        prompt_len=args.prompt_len, min_new_tokens=args.min_new_tokens,
-        max_new_tokens=args.max_new_tokens, vocab=tc.vocab,
-        seed=args.seed, cells=args.cells))
+    trace = make_trace(args, tc.vocab)
 
     server = None
     port = args.cloud_port
@@ -145,7 +130,8 @@ def run_tcp_vs_sim(args, tc, dc, dp, sim_rep, cache_len, obs=None):
             port = server.port
             print(f"[tcp] in-process cloud server on "
                   f"{args.cloud_host}:{port}")
-        client = EdgeClient(dc, dp, method, ecfg, cfg,
+        client = EdgeClient(dc, dp, method_config(args),
+                            engine_config(args), serve_config(args),
                             arch=args.arch, smoke=args.smoke,
                             host=args.cloud_host, port=port,
                             seed=args.seed, obs=obs)
@@ -192,7 +178,7 @@ def run_tcp_vs_sim(args, tc, dc, dp, sim_rep, cache_len, obs=None):
     raise SystemExit(1)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -293,7 +279,72 @@ def main():
     ap.add_argument("--n-pages", type=int, default=0,
                     help="trace mode: KV pool size in pages (0 = auto: "
                          "slots x pages-per-slot, the dense footprint)")
-    args = ap.parse_args()
+    return ap
+
+
+def build_models(args):
+    """(target cfg, draft cfg, target params, draft params) as every
+    serving entry point builds them: ``--arch`` (cut by ``--smoke``),
+    its ``draft_variant(--draft-scale)``, params from the checkpoints or
+    random from ``--seed`` (target seed+1, draft seed+2)."""
+    tc = configs.get_config(args.arch)
+    if args.smoke:
+        tc = configs.smoke_variant(tc)
+    dc = configs.draft_variant(tc, args.draft_scale)
+    tp = load_or_init(tc, args.target_ckpt, args.seed + 1)
+    dp = load_or_init(dc, args.draft_ckpt, args.seed + 2)
+    return tc, dc, tp, dp
+
+
+def method_config(args) -> MethodConfig:
+    return MethodConfig(args.method, K=args.K, ell=args.ell,
+                        alpha=args.alpha, eta=args.eta)
+
+
+def engine_config(args, collect_theory: bool = False) -> EngineConfig:
+    return EngineConfig(L_max=args.L_max, bit_budget=args.bit_budget,
+                        temperature=args.temperature,
+                        wire_codec=args.wire_codec,
+                        budget_model=args.budget_model,
+                        collect_theory=collect_theory)
+
+
+def build_engine(args, tc, dc, tp, dp,
+                 collect_theory: bool = False) -> EdgeCloudEngine:
+    return EdgeCloudEngine(
+        dc, dp, tc, tp, method_config(args),
+        engine_config(args, collect_theory),
+        ChannelConfig(uplink_bps=args.uplink_bps,
+                      downlink_bps=args.downlink_mbps * 1e6),
+        seed=args.seed)
+
+
+def serve_config(args) -> ServeConfig:
+    """Trace-mode serving config; the per-slot cache holds the prompt,
+    the longest generation and one draft window (--cache-len 0)."""
+    cache_len = args.cache_len or (
+        args.prompt_len + args.max_new_tokens + args.L_max + 8)
+    return ServeConfig(
+        max_batch=args.max_batch, queue_cap=args.queue_cap,
+        policy=args.policy, cache_len=cache_len,
+        page_size=args.page_size, n_pages=args.n_pages or None,
+        pipeline=args.pipeline, speculate=not args.no_speculate,
+        n_cells=args.cells, verdict_batch=args.verdict_batch)
+
+
+def make_trace(args, vocab: int):
+    """The seeded Poisson trace; a fresh list each call, since a run
+    mutates its Request objects."""
+    return poisson_trace(TraceConfig(
+        n_requests=args.n_requests, rate_rps=args.rate,
+        prompt_len=args.prompt_len, min_new_tokens=args.min_new_tokens,
+        max_new_tokens=args.max_new_tokens, vocab=vocab,
+        seed=args.seed, cells=args.cells))
+
+
+def main(argv=None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
     if args.transport == "tcp" and not args.trace:
         ap.error("--transport tcp requires --trace")
     if (args.trace_out or args.metrics_out) and not args.trace:
@@ -301,50 +352,17 @@ def main():
     obs = build_obs(args) if (args.trace_out or args.metrics_out) \
         else None
 
-    tc = configs.get_config(args.arch)
-    if args.smoke:
-        tc = configs.smoke_variant(tc)
-    dc = configs.draft_variant(tc, args.draft_scale)
-    tp = load_or_init(tc, args.target_ckpt, args.seed + 1)
-    dp = load_or_init(dc, args.draft_ckpt, args.seed + 2)
-
-    eng = EdgeCloudEngine(
-        dc, dp, tc, tp,
-        MethodConfig(args.method, K=args.K, ell=args.ell, alpha=args.alpha,
-                     eta=args.eta),
-        EngineConfig(L_max=args.L_max, bit_budget=args.bit_budget,
-                     temperature=args.temperature,
-                     wire_codec=args.wire_codec,
-                     budget_model=args.budget_model,
-                     # dense q/p arrays for the Theorem-1 decomposition;
-                     # records only — tokens are unaffected
-                     collect_theory=bool(obs and obs.decomp)),
-        ChannelConfig(uplink_bps=args.uplink_bps,
-                      downlink_bps=args.downlink_mbps * 1e6),
-        seed=args.seed)
+    tc, dc, tp, dp = build_models(args)
+    # dense q/p arrays for the Theorem-1 decomposition; records only —
+    # tokens are unaffected
+    eng = build_engine(args, tc, dc, tp, dp,
+                       collect_theory=bool(obs and obs.decomp))
 
     if args.trace:
-        cache_len = args.cache_len or (
-            args.prompt_len + args.max_new_tokens + args.L_max + 8)
-        trace = poisson_trace(TraceConfig(
-            n_requests=args.n_requests, rate_rps=args.rate,
-            prompt_len=args.prompt_len,
-            min_new_tokens=args.min_new_tokens,
-            max_new_tokens=args.max_new_tokens,
-            vocab=tc.vocab, seed=args.seed, cells=args.cells))
-        sess = ServeSession(eng, ServeConfig(
-            max_batch=args.max_batch, queue_cap=args.queue_cap,
-            policy=args.policy, cache_len=cache_len,
-            page_size=args.page_size,
-            n_pages=args.n_pages or None,
-            pipeline=args.pipeline,
-            speculate=not args.no_speculate,
-            n_cells=args.cells,
-            verdict_batch=args.verdict_batch), obs=obs)
-        rep = sess.run_trace(trace)
+        rep = ServeSession(eng, serve_config(args), obs=obs).run_trace(
+            make_trace(args, tc.vocab))
         if args.transport == "tcp":
-            return run_tcp_vs_sim(args, tc, dc, dp, rep, cache_len,
-                                  obs=obs)
+            return run_tcp_vs_sim(args, tc, dc, dp, rep, obs=obs)
         kv = (f"paged({args.page_size}-tok pages)" if args.page_size
               else "dense")
         print(f"[serve --trace] {tc.name} <- {dc.name}  "
@@ -383,4 +401,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -38,7 +38,13 @@ from repro.models.layers import (compute_dtype, embed_apply, init_embed,
 # ----------------------------------------------------------------------
 # Init
 # ----------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key):
+    """Random parameters, stored in the config's compute dtype (bf16 at
+    published widths, so Qwen2.5-3B's 3.1 B parameters take 6.2 GB of
+    HBM, not 12.4 GB; every matmul computes in that dtype anyway).  Under
+    jit the float32 draws are fused into the cast and never materialise
+    as whole stacked leaves."""
     ks = split_keys(key, 5)
     params = {
         "embed": init_embed(ks[0], cfg),
@@ -53,7 +59,10 @@ def init_params(cfg: ModelConfig, key):
             f"l{i}": tfm.init_block(pks[i], cfg, "attn", "mlp", cross=cross)
             for i in range(cfg.n_prefix_layers)}
     params["body"] = tfm.init_body(ks[3], cfg, cross=cross)
-    return params
+    dt = compute_dtype(cfg)
+    return jax.tree.map(
+        lambda a: a.astype(dt) if jnp.issubdtype(a.dtype, jnp.floating)
+        else a, params)
 
 
 def param_count(params) -> int:

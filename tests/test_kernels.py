@@ -62,7 +62,7 @@ def test_bisection_brackets_kth_largest(V, K):
     far tail the deviation is bounded by K * 2^-40 probability mass —
     below one lattice unit for any practical ℓ.)"""
     q = jax.nn.softmax(_logits(jax.random.PRNGKey(K), 4, V), axis=-1)
-    tau = np.asarray(k.topk_threshold_call(q, K))
+    tau = np.asarray(k.topk_threshold_call(q, K, interpret=True))
     kth = np.asarray(ref.kth_largest_ref(q, K))
     assert np.all(tau[:, 0] <= kth + 1e-12)
     assert np.all(kth <= tau[:, 1] + 1e-12)
