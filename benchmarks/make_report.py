@@ -1,4 +1,5 @@
-"""Assemble EXPERIMENTS.md sections from the dry-run JSONs and bench CSVs.
+"""Assemble EXPERIMENTS.md sections from the dry-run JSONs and the paper
+tables' CSVs.
 
     PYTHONPATH=src python -m benchmarks.make_report > EXPERIMENTS.generated.md
 """
@@ -8,8 +9,6 @@ import csv
 import glob
 import json
 import os
-
-from benchmarks import roofline
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -67,16 +66,6 @@ def dryrun_section():
     return "\n".join(out)
 
 
-def roofline_section(dirname="experiments/dryrun_opt", tag="optimized"):
-    rows = roofline.build_table(os.path.join(ROOT, dirname))
-    out = [f"### Roofline terms — single pod (16×16), {tag} sharding", "",
-           roofline.markdown_table(rows), ""]
-    counts = {b: sum(r["bottleneck"] == b for r in rows)
-              for b in ("compute", "memory", "collective")}
-    out.append(f"Bottleneck split: {counts}.")
-    return "\n".join(out)
-
-
 def csv_table(name, cols=None, title=""):
     path = os.path.join(ROOT, "experiments", "bench", f"{name}.csv")
     if not os.path.exists(path):
@@ -104,30 +93,6 @@ def csv_table(name, cols=None, title=""):
 def main():
     print(dryrun_section())
     print()
-    print("## §Roofline")
-    print()
-    print("Terms: compute = FLOPs/(chips × 197 TF bf16); memory = analytic "
-          "HBM bytes/(chips × 819 GB/s); collective = per-device HLO "
-          "collective bytes / 50 GB/s (conservative SINGLE-link ICI — "
-          "multi-link torus axes would divide this by the per-axis link "
-          "count, so collective terms are upper bounds). 6ND/HLO = "
-          "mode-appropriate model FLOPs (6ND train / 2ND inference) over "
-          "compiled FLOPs — low values expose replicated or capacity-"
-          "padded compute; >1 means the compiled path does less than the "
-          "dense-equivalent model math (e.g. sliding-window attention).")
-    print()
-    print(roofline_section("experiments/dryrun", "baseline"))
-    print()
-    print(roofline_section("experiments/dryrun_opt", "optimized"))
-    print()
-    rows_mp = roofline.build_table(
-        os.path.join(ROOT, "experiments/dryrun_opt"), mesh="pod2x16x16")
-    if rows_mp:
-        print("### Roofline terms — multi-pod (2×16×16), optimized "
-              "sharding")
-        print()
-        print(roofline.markdown_table(rows_mp))
-        print()
     print("## §Paper-validation tables")
     print()
     for name, cols, title in [
@@ -137,7 +102,6 @@ def main():
         ("fig6_compare", None, "Fig. 6 — methods incl. baselines"),
         ("bits_table", None, "Bits/token accounting (eqs. 1/2/5)"),
         ("thm_checks", None, "Theorem 1 & 2 empirical checks"),
-        ("kernel_bench", None, "Kernel microbench"),
         ("ell_resolution", None,
          "Extra ablation — lattice resolution ℓ (Thm-1 K/4ℓ term)"),
         ("draft_scale", None,

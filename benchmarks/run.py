@@ -43,8 +43,7 @@ def main():
 
     from benchmarks import (bits_table, draft_scale, ell_resolution,
                             fig2_temperature, fig4_hparams, fig5_adaptivity,
-                            fig6_compare, kernel_bench, roofline,
-                            serve_load, thm_checks)
+                            fig6_compare, thm_checks)
 
     reg("fig2_temperature", lambda: _rows_to_csv(
         "fig2", fig2_temperature.run(q)[0], "latency_per_batch_s",
@@ -63,26 +62,12 @@ def main():
         scale=1.0))
     reg("thm_checks", lambda: _rows_to_csv(
         "thm", thm_checks.run(q)[0], "measured", "holds", scale=1.0))
-    reg("kernel_bench", lambda: _rows_to_csv(
-        "kernel", kernel_bench.run(q)[0], "us_per_call",
-        "hbm_sweeps_model", scale=1.0))
     reg("ell_resolution", lambda: _rows_to_csv(
         "ell", ell_resolution.run(q)[0], "latency_per_batch_s",
         "resampling_rate"))
     reg("draft_scale", lambda: _rows_to_csv(
         "draft", draft_scale.run(q)[0], "latency_per_batch_s",
         "accept_rate"))
-    reg("serve_load", lambda: _rows_to_csv(
-        "serve", serve_load.run(smoke=q)[0], "latency_p50_s",
-        "throughput_tok_s"))
-
-    def roofline_rows():
-        rows = roofline.build_table()
-        return [f"roofline[{r['arch']}_{r['shape']}],"
-                f"{r['t_compute_s']*1e6:.1f},"
-                f"{r['bottleneck']}:{r['useful_ratio']:.2f}"
-                for r in rows]
-    reg("roofline", roofline_rows)
 
     print("name,us_per_call,derived")
     failed = []

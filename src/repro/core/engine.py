@@ -66,6 +66,7 @@ from repro.core import wire as wire_mod
 from repro.core.pages import PageAllocator
 from repro.models import model as model_mod
 from repro.models.attention import PagedSpec, sanitize_page_table
+from repro.obs.handle import NULL_OBS, Obs
 
 SEQ_BLOCKS = ("mamba", "mlstm", "slstm")
 
@@ -224,10 +225,11 @@ class EdgeDraftEngine:
 
     def __init__(self, dc: ModelConfig, dp, method: MethodConfig,
                  engine: EngineConfig, fmt: wire_mod.WireFormat,
-                 seed: int = 0):
+                 seed: int = 0, obs: Optional[Obs] = None):
         self.dc, self.dp = dc, dp
         self.m, self.e, self.fmt = method, engine, fmt
         self.seed = seed
+        self.obs = obs if obs is not None else NULL_OBS
         self.V = dc.vocab
         self.stateful = _is_stateful(dc)
         self._draft_jit = jax.jit(self._draft_round)
@@ -291,15 +293,22 @@ class EdgeDraftEngine:
 
         def step(carry, i):
             cache, tok, beta, keys, pos = carry
-            keys, k1 = _split_rows(keys)
-            logits, cache = model_mod.decode_step(ecfg, dp, tok, cache, pos)
-            q = sqs_mod.softmax_temp(logits, self.e.temperature)
-            r, bits, gap_bits = self._sparsify(q, beta, logits=logits)
-            nxt = jax.vmap(jax.random.categorical)(
-                k1, jnp.log(jnp.maximum(r.q_hat, 1e-30))).astype(jnp.int32)
-            new_beta = conformal.update(beta, r.dropped, self.m.alpha,
-                                        self.m.eta) \
-                if self.m.name == "csqs" else beta
+            # named scopes only label the ops (HLO op_name metadata), so
+            # a profile can split the step's device time into the draft
+            # model and SQS
+            with jax.named_scope("slm"):
+                logits, cache = model_mod.decode_step(ecfg, dp, tok, cache,
+                                                      pos)
+            with jax.named_scope("sqs"):
+                keys, k1 = _split_rows(keys)
+                q = sqs_mod.softmax_temp(logits, self.e.temperature)
+                r, bits, gap_bits = self._sparsify(q, beta, logits=logits)
+                nxt = jax.vmap(jax.random.categorical)(
+                    k1, jnp.log(jnp.maximum(r.q_hat, 1e-30))
+                ).astype(jnp.int32)
+                new_beta = conformal.update(beta, r.dropped, self.m.alpha,
+                                            self.m.eta) \
+                    if self.m.name == "csqs" else beta
             snap = {p: cache["body"][p] for p in seq_p}
             ys = dict(token=nxt, q_hat=r.q_hat, q=q, bits=bits,
                       gap_bits=gap_bits, dropped=r.dropped, K=r.K,
@@ -350,36 +359,39 @@ class EdgeDraftEngine:
 
     def admit(self, slot: int, prompt, pt_row, seed: int,
               wire_codec: Optional[str] = None):
-        S0 = int(prompt.shape[0])
-        _, cache1 = self._prefill_jit(self.dp, prompt[None, :-1])
-        self.dcache = model_mod.write_prefill_to_slot(
-            self.dc, self.dcache, cache1, slot, pt_row, S0 - 1)
-        key = row_key(seed, 0)
-        self.x_last = self.x_last.at[slot].set(prompt[-1])
-        self.pos = self.pos.at[slot].set(S0 - 1)
-        self.beta = conformal.admit_rows(
-            self.beta, jnp.arange(self.B) == slot, self.m.beta0)
-        self.keys = self.keys.at[slot].set(key)
-        self.rep_x = self.rep_x.at[slot].set(prompt[-1])
-        self.rep_pos = self.rep_pos.at[slot].set(S0 - 1)
-        self.rep_beta = self.rep_beta.at[slot].set(self.m.beta0)
-        self.rep_key = self.rep_key.at[slot].set(key)
-        self.slot_codec[slot] = wire_codec or self.fmt.codec
-        self.coded_scale[slot] = 1.0
+        with self.obs.tracer.wall("edge.admit", args={"slots": [slot]}):
+            S0 = int(prompt.shape[0])
+            _, cache1 = self._prefill_jit(self.dp, prompt[None, :-1])
+            self.dcache = model_mod.write_prefill_to_slot(
+                self.dc, self.dcache, cache1, slot, pt_row, S0 - 1)
+            key = row_key(seed, 0)
+            self.x_last = self.x_last.at[slot].set(prompt[-1])
+            self.pos = self.pos.at[slot].set(S0 - 1)
+            self.beta = conformal.admit_rows(
+                self.beta, jnp.arange(self.B) == slot, self.m.beta0)
+            self.keys = self.keys.at[slot].set(key)
+            self.rep_x = self.rep_x.at[slot].set(prompt[-1])
+            self.rep_pos = self.rep_pos.at[slot].set(S0 - 1)
+            self.rep_beta = self.rep_beta.at[slot].set(self.m.beta0)
+            self.rep_key = self.rep_key.at[slot].set(key)
+            self.slot_codec[slot] = wire_codec or self.fmt.codec
+            self.coded_scale[slot] = 1.0
 
     def set_tables(self, pt):
         self.dcache = model_mod.set_page_tables(self.dcache, pt)
 
     # -- drafting ------------------------------------------------------
-    def _run_draft(self, x_in, pos_in, beta_in, key_in):
-        new_keys, kd = _split_rows(key_in)
-        t0 = time.perf_counter()
-        dcache, ys = self._draft_jit(self.dp, self.dcache, x_in, pos_in,
-                                     beta_in, kd)
-        jax.block_until_ready(ys["token"])
-        t_slm = time.perf_counter() - t0
+    def _run_draft(self, x_in, pos_in, beta_in, kd, args: dict):
+        """The draft step program on the device; ``t_slm`` times the
+        call and its block_until_ready, inside the ``edge.step`` span."""
+        with self.obs.tracer.wall("edge.step", args=args):
+            t0 = time.perf_counter()
+            dcache, ys = self._draft_jit(self.dp, self.dcache, x_in, pos_in,
+                                         beta_in, kd)
+            jax.block_until_ready(ys["token"])
+            t_slm = time.perf_counter() - t0
         self.dcache = dcache
-        return ys, new_keys, t_slm
+        return ys, t_slm
 
     def _live_counts(self, bits: np.ndarray, mask: np.ndarray):
         """Budget-driven L^t (paper §4): stop when estimated wire bits
@@ -411,31 +423,38 @@ class EdgeDraftEngine:
         for slot, s in scale_next.items():
             self.coded_scale[slot] = s
 
-    def _build_batch(self, ys, mask: np.ndarray, t_slm: float) -> DraftBatch:
+    def _build_batch(self, ys, mask: np.ndarray, t_slm: float,
+                     args: dict) -> DraftBatch:
         L = self.e.L_max
-        drafts = np.asarray(ys["token"])                  # (L+1, B)
-        betas = np.asarray(ys["beta"])                    # (L+1, B)
-        bits = np.asarray(ys["bits"][:L]).T               # (B, L)
-        gap_bits = np.asarray(ys["gap_bits"][:L]).T
-        dropped = np.asarray(ys["dropped"]).T             # (B, L+1)
-        Ks = np.asarray(ys["K"][:L]).T
-        live, n_live = self._live_counts(bits, mask)
-        packed, scale_next = {}, {}
-        for slot in np.nonzero(mask)[0]:
-            # slice the committed row ON DEVICE: per-slot drafts
+        slots = [int(s) for s in np.nonzero(mask)[0]]
+        tr, mx = self.obs.tracer, self.obs.metrics
+        mx.counter("edge.rows_computed").inc(self.B)
+        mx.counter("edge.rows_requested").inc(len(slots))
+        with tr.wall("edge.fetch", args=args):
+            drafts = np.asarray(ys["token"])              # (L+1, B)
+            betas = np.asarray(ys["beta"])                # (L+1, B)
+            bits = np.asarray(ys["bits"][:L]).T           # (B, L)
+            gap_bits = np.asarray(ys["gap_bits"][:L]).T
+            dropped = np.asarray(ys["dropped"]).T         # (B, L+1)
+            Ks = np.asarray(ys["K"][:L]).T
+            # slice the committed rows ON DEVICE: per-slot drafts
             # (pipelined serving) must not ship the whole (L, B, V)
             # batch of distributions to host every call
-            qhat_row = np.asarray(ys["q_hat"][:L, int(slot)])
-            payload = wire_mod.build_draft_payload(
-                self.fmt, drafts[:, slot], qhat_row, betas[:, slot],
-                int(n_live[slot]))
-            data = self.fmt.pack_draft(payload,
-                                       codec=self.slot_codec[int(slot)])
-            packed[int(slot)] = data
-            if self.e.budget_model == "calibrated":
-                est = float(bits[slot, :int(n_live[slot])].sum())
-                scale_next[int(slot)] = self._scale_update(
-                    int(slot), len(data) * 8.0, est)
+            qhat_rows = {s: np.asarray(ys["q_hat"][:L, s]) for s in slots}
+        packed, scale_next = {}, {}
+        with tr.wall("edge.pack", args=args):
+            live, n_live = self._live_counts(bits, mask)
+            for slot in slots:
+                payload = wire_mod.build_draft_payload(
+                    self.fmt, drafts[:, slot], qhat_rows[slot],
+                    betas[:, slot], int(n_live[slot]))
+                data = self.fmt.pack_draft(payload,
+                                           codec=self.slot_codec[slot])
+                packed[slot] = data
+                if self.e.budget_model == "calibrated":
+                    est = float(bits[slot, :int(n_live[slot])].sum())
+                    scale_next[slot] = self._scale_update(
+                        slot, len(data) * 8.0, est)
         return DraftBatch(ys=ys, drafts=drafts, betas=betas, bits=bits,
                           gap_bits=gap_bits, dropped=dropped, Ks=Ks,
                           live=live, n_live=n_live, packed=packed,
@@ -445,18 +464,23 @@ class EdgeDraftEngine:
         """One draft round, committing key-chain/replay state for rows
         in ``mask``; other rows replay their registers (bit-identical
         recompute, no state advance)."""
-        mj = jnp.asarray(mask)
-        x_in = jnp.where(mj, self.x_last, self.rep_x)
-        pos_in = jnp.where(mj, self.pos, self.rep_pos)
-        beta_in = jnp.where(mj, self.beta, self.rep_beta)
-        key_in = jnp.where(mj[:, None], self.keys, self.rep_key)
-        ys, new_keys, t_slm = self._run_draft(x_in, pos_in, beta_in, key_in)
-        self.keys = jnp.where(mj[:, None], new_keys, self.keys)
-        self.rep_x = x_in
-        self.rep_pos = pos_in
-        self.rep_beta = beta_in
-        self.rep_key = jnp.where(mj[:, None], key_in, self.rep_key)
-        batch = self._build_batch(ys, mask, t_slm)
+        args = {"slots": [int(s) for s in np.nonzero(mask)[0]]}
+        with self.obs.tracer.wall("edge.prep", args=args):
+            mj = jnp.asarray(mask)
+            x_in = jnp.where(mj, self.x_last, self.rep_x)
+            pos_in = jnp.where(mj, self.pos, self.rep_pos)
+            beta_in = jnp.where(mj, self.beta, self.rep_beta)
+            key_in = jnp.where(mj[:, None], self.keys, self.rep_key)
+            new_keys, kd = _split_rows(key_in)
+            # commit the key chain and replay registers (inputs only:
+            # the step's outputs play no part in them)
+            self.keys = jnp.where(mj[:, None], new_keys, self.keys)
+            self.rep_x = x_in
+            self.rep_pos = pos_in
+            self.rep_beta = beta_in
+            self.rep_key = jnp.where(mj[:, None], key_in, self.rep_key)
+        ys, t_slm = self._run_draft(x_in, pos_in, beta_in, kd, args)
+        batch = self._build_batch(ys, mask, t_slm, args)
         # a real draft commits its coded-size observations immediately;
         # speculative drafts carry theirs in SpecDraft.scale_next and
         # commit only when the premise is confirmed — so the EMA
@@ -488,17 +512,21 @@ class EdgeDraftEngine:
             "cache — sequential-state drafts must run lockstep"
         onehot = np.zeros((self.B,), bool)
         onehot[slot] = True
-        mj = jnp.asarray(onehot)
-        x_in = jnp.where(mj, jnp.int32(x_guess), self.rep_x)
-        pos_in = jnp.where(mj, jnp.int32(pos_next), self.rep_pos)
-        beta_in = jnp.where(mj, jnp.float32(beta_next), self.rep_beta)
-        key_in = jnp.where(mj[:, None], self.keys, self.rep_key)
-        base_key = self.keys[slot]
-        ys, new_keys, t_slm = self._run_draft(x_in, pos_in, beta_in, key_in)
-        batch = self._build_batch(ys, onehot, t_slm)
+        args = {"slots": [slot], "speculative": True}
+        with self.obs.tracer.wall("edge.prep", args=args):
+            mj = jnp.asarray(onehot)
+            x_in = jnp.where(mj, jnp.int32(x_guess), self.rep_x)
+            pos_in = jnp.where(mj, jnp.int32(pos_next), self.rep_pos)
+            beta_in = jnp.where(mj, jnp.float32(beta_next), self.rep_beta)
+            key_in = jnp.where(mj[:, None], self.keys, self.rep_key)
+            base_key = self.keys[slot]
+            new_keys, kd = _split_rows(key_in)
+            new_key = new_keys[slot]
+        ys, t_slm = self._run_draft(x_in, pos_in, beta_in, kd, args)
+        batch = self._build_batch(ys, onehot, t_slm, args)
         return SpecDraft(slot=slot, in_x=int(x_guess), in_pos=int(pos_next),
                          in_beta=float(beta_next), base_key=base_key,
-                         new_key=new_keys[slot],
+                         new_key=new_key,
                          round=self.pending_round(batch, slot),
                          scale_next=batch.scale_next)
 
@@ -573,26 +601,29 @@ class CloudVerifyEngine:
 
     def __init__(self, tc: ModelConfig, tp, method: MethodConfig,
                  engine: EngineConfig, fmt: wire_mod.WireFormat,
-                 seed: int = 0):
+                 seed: int = 0, obs: Optional[Obs] = None):
         self.tc, self.tp = tc, tp
         self.m, self.e, self.fmt = method, engine, fmt
         self.seed = seed
+        self.obs = obs if obs is not None else NULL_OBS
         self.V = tc.vocab
         self.stateful = _is_stateful(tc)
         self._verify_jit = jax.jit(self._verify_round)
 
     def _verify_round(self, tp, cache, tokens_in, pos, q_hat, live, key):
         """tokens_in: (B, L+1) = [x_last, d_1..d_L]."""
-        if self.stateful:
-            logits, cache, traj = model_mod.extend_step(
-                self.tc, tp, tokens_in, cache, pos, collect_traj=True)
-        else:
-            logits, cache = model_mod.extend_step(self.tc, tp, tokens_in,
-                                                  cache, pos)
-            traj = None
-        p = sqs_mod.softmax_temp(logits, self.e.temperature)  # (B, L+1, V)
-        res = verify_mod.verify(key, tokens_in[:, 1:], q_hat, p, live)
-        return res, p, cache, traj
+        with jax.named_scope("llm"):
+            if self.stateful:
+                logits, cache, traj = model_mod.extend_step(
+                    self.tc, tp, tokens_in, cache, pos, collect_traj=True)
+            else:
+                logits, cache = model_mod.extend_step(self.tc, tp,
+                                                      tokens_in, cache, pos)
+                traj = None
+        with jax.named_scope("verify"):
+            p = sqs_mod.softmax_temp(logits, self.e.temperature)
+            res = verify_mod.verify(key, tokens_in[:, 1:], q_hat, p, live)
+        return res, p, cache, traj                # p: (B, L+1, V)
 
     # -- slot/state lifecycle ------------------------------------------
     def _alloc_state(self, B: int):
@@ -633,21 +664,22 @@ class CloudVerifyEngine:
 
     def admit(self, slot: int, prompt, pt_row, seed: int,
               wire_codec: Optional[str] = None):
-        S0 = int(prompt.shape[0])
-        _, cache1 = self._prefill_jit(self.tp, prompt[None, :-1])
-        self.tcache = model_mod.write_prefill_to_slot(
-            self.tc, self.tcache, cache1, slot, pt_row, S0 - 1)
-        self.slot_codec[slot] = wire_codec or self.fmt.codec
-        key = cloud_row_key(seed, 0)
-        self.x_last = self.x_last.at[slot].set(prompt[-1])
-        self.pos = self.pos.at[slot].set(S0 - 1)
-        self.keys = self.keys.at[slot].set(key)
-        self.rep_tokens = self.rep_tokens.at[slot].set(0)
-        self.rep_qhat = self.rep_qhat.at[slot].set(0.0)
-        self.rep_live = self.rep_live.at[slot].set(False)
-        self.rep_x = self.rep_x.at[slot].set(prompt[-1])
-        self.rep_pos = self.rep_pos.at[slot].set(S0 - 1)
-        self.rep_key = self.rep_key.at[slot].set(key)
+        with self.obs.tracer.wall("cloud.admit", args={"slots": [slot]}):
+            S0 = int(prompt.shape[0])
+            _, cache1 = self._prefill_jit(self.tp, prompt[None, :-1])
+            self.tcache = model_mod.write_prefill_to_slot(
+                self.tc, self.tcache, cache1, slot, pt_row, S0 - 1)
+            self.slot_codec[slot] = wire_codec or self.fmt.codec
+            key = cloud_row_key(seed, 0)
+            self.x_last = self.x_last.at[slot].set(prompt[-1])
+            self.pos = self.pos.at[slot].set(S0 - 1)
+            self.keys = self.keys.at[slot].set(key)
+            self.rep_tokens = self.rep_tokens.at[slot].set(0)
+            self.rep_qhat = self.rep_qhat.at[slot].set(0.0)
+            self.rep_live = self.rep_live.at[slot].set(False)
+            self.rep_x = self.rep_x.at[slot].set(prompt[-1])
+            self.rep_pos = self.rep_pos.at[slot].set(S0 - 1)
+            self.rep_key = self.rep_key.at[slot].set(key)
 
     def set_tables(self, pt):
         self.tcache = model_mod.set_page_tables(self.tcache, pt)
@@ -663,56 +695,68 @@ class CloudVerifyEngine:
         per payload — including the Alg.-1 β backtrack computed from
         the wire trajectory."""
         B, L = self.B, self.e.L_max
-        tok_np = np.zeros((B, L), np.int32)
-        qhat_np = np.zeros((B, L, self.V), np.float32)
-        live_np = np.zeros((B, L), bool)
-        for slot, p in payloads.items():
-            assert mask[slot], f"payload for non-committed slot {slot}"
-            tok_np[slot], qhat_np[slot], live_np[slot] = \
-                wire_mod.draft_arrays(self.fmt, p)
-        mj = jnp.asarray(mask)
-        tokens = jnp.where(mj[:, None], jnp.asarray(tok_np),
-                           self.rep_tokens)
-        qhat = jnp.where(mj[:, None, None], jnp.asarray(qhat_np),
-                         self.rep_qhat)
-        live = jnp.where(mj[:, None], jnp.asarray(live_np), self.rep_live)
-        x_in = jnp.where(mj, self.x_last, self.rep_x)
-        pos_in = jnp.where(mj, self.pos, self.rep_pos)
-        key_in = jnp.where(mj[:, None], self.keys, self.rep_key)
-        new_keys, kv = _split_rows(key_in)
-        tokens_in = jnp.concatenate([x_in[:, None], tokens], axis=1)
-        t0 = time.perf_counter()
-        res, p_dists, tcache, traj = self._verify_jit(
-            self.tp, self.tcache, tokens_in, pos_in, qhat, live, kv)
-        jax.block_until_ready(res.n_accept)
-        t_llm = time.perf_counter() - t0
-        T = res.n_accept
-        T_eff = jnp.where(mj, T, 0)
-        self.tcache = rollback_cache(self.tc, tcache, traj, T_eff + 1)
-        self.pos = jnp.where(mj, pos_in + T + 1, self.pos)
-        self.x_last = jnp.where(mj, res.new_token, self.x_last)
-        self.keys = jnp.where(mj[:, None], new_keys, self.keys)
-        self.rep_tokens = tokens
-        self.rep_qhat = qhat
-        self.rep_live = live
-        self.rep_x = x_in
-        self.rep_pos = pos_in
-        self.rep_key = jnp.where(mj[:, None], key_in, self.rep_key)
-        T_np = np.asarray(T)
-        nt_np = np.asarray(res.new_token)
-        rej_np = np.asarray(res.rejected)
-        verdicts = {
-            slot: wire_mod.VerdictPayload(
-                n_accept=int(T_np[slot]),
-                new_token=int(nt_np[slot]),
-                beta_next=conformal.backtrack_wire(p.betas,
-                                                   int(T_np[slot])))
-            for slot, p in payloads.items()
-        }
-        return VerifyBatch(verdicts=verdicts, T=T_np, new_token=nt_np,
-                           rejected=rej_np,
-                           p=np.asarray(p_dists) if collect_p else None,
-                           t_llm=t_llm)
+        tr, mx = self.obs.tracer, self.obs.metrics
+        args = {"slots": sorted(payloads)}
+        if mx.enabled:
+            mx.counter("wire.payloads").inc(len(payloads))
+            mx.counter("wire.live_drafts").inc(
+                sum(p.n_drafts for p in payloads.values()))
+            mx.counter("wire.support_tokens").inc(
+                sum(len(s) for p in payloads.values() for s in p.supports))
+        with tr.wall("cloud.upload", args=args):
+            tok_np = np.zeros((B, L), np.int32)
+            qhat_np = np.zeros((B, L, self.V), np.float32)
+            live_np = np.zeros((B, L), bool)
+            for slot, p in payloads.items():
+                assert mask[slot], f"payload for non-committed slot {slot}"
+                tok_np[slot], qhat_np[slot], live_np[slot] = \
+                    wire_mod.draft_arrays(self.fmt, p)
+            mj = jnp.asarray(mask)
+            tokens = jnp.where(mj[:, None], jnp.asarray(tok_np),
+                               self.rep_tokens)
+            qhat = jnp.where(mj[:, None, None], jnp.asarray(qhat_np),
+                             self.rep_qhat)
+            live = jnp.where(mj[:, None], jnp.asarray(live_np),
+                             self.rep_live)
+            x_in = jnp.where(mj, self.x_last, self.rep_x)
+            pos_in = jnp.where(mj, self.pos, self.rep_pos)
+            key_in = jnp.where(mj[:, None], self.keys, self.rep_key)
+            new_keys, kv = _split_rows(key_in)
+            tokens_in = jnp.concatenate([x_in[:, None], tokens], axis=1)
+        with tr.wall("cloud.step", args=args):
+            t0 = time.perf_counter()
+            res, p_dists, tcache, traj = self._verify_jit(
+                self.tp, self.tcache, tokens_in, pos_in, qhat, live, kv)
+            jax.block_until_ready(res.n_accept)
+            t_llm = time.perf_counter() - t0
+        with tr.wall("cloud.commit", args=args):
+            T = res.n_accept
+            T_eff = jnp.where(mj, T, 0)
+            self.tcache = rollback_cache(self.tc, tcache, traj, T_eff + 1)
+            self.pos = jnp.where(mj, pos_in + T + 1, self.pos)
+            self.x_last = jnp.where(mj, res.new_token, self.x_last)
+            self.keys = jnp.where(mj[:, None], new_keys, self.keys)
+            self.rep_tokens = tokens
+            self.rep_qhat = qhat
+            self.rep_live = live
+            self.rep_x = x_in
+            self.rep_pos = pos_in
+            self.rep_key = jnp.where(mj[:, None], key_in, self.rep_key)
+            T_np = np.asarray(T)
+            nt_np = np.asarray(res.new_token)
+            rej_np = np.asarray(res.rejected)
+            verdicts = {
+                slot: wire_mod.VerdictPayload(
+                    n_accept=int(T_np[slot]),
+                    new_token=int(nt_np[slot]),
+                    beta_next=conformal.backtrack_wire(p.betas,
+                                                       int(T_np[slot])))
+                for slot, p in payloads.items()
+            }
+            return VerifyBatch(verdicts=verdicts, T=T_np, new_token=nt_np,
+                               rejected=rej_np,
+                               p=np.asarray(p_dists) if collect_p else None,
+                               t_llm=t_llm)
 
 
 # ======================================================================
@@ -732,11 +776,17 @@ class EdgeEngineBase:
     CONSTRUCTION: there is exactly one implementation of every
     token-affecting edge step, and subclasses only override how the
     verify peer is reached (``_init_peer_slots`` / ``_admit_peer`` /
-    ``_push_tables``)."""
+    ``_push_tables``).
+
+    ``obs`` (default ``NULL_OBS``) reaches every actor: wall spans
+    around the step programs and the host work between them, and the
+    wire and row counters.  ``ServeSession`` hands its own ``Obs`` in,
+    so one handle covers the serving loop and the engine."""
 
     def __init__(self, draft_cfg: ModelConfig, draft_params,
                  method: MethodConfig, engine: EngineConfig,
-                 channel: channel_mod.ChannelConfig, seed: int):
+                 channel: channel_mod.ChannelConfig, seed: int,
+                 obs: Optional[Obs] = None):
         self.dc, self.dp = draft_cfg, draft_params
         self.m, self.e, self.ch = method, engine, channel
         self.seed = seed
@@ -749,10 +799,23 @@ class EdgeEngineBase:
             mode="raw" if method.name == "uncompressed" else "lattice",
             codec=engine.wire_codec)
         self.edge = EdgeDraftEngine(draft_cfg, draft_params, method,
-                                    engine, self.fmt, seed)
+                                    engine, self.fmt, seed, obs)
         self.peer_stateful = False    # does the verify-side model carry
         self.paged = False            # recurrent state? (subclasses set)
         self.alloc: Optional[PageAllocator] = None
+
+    # -- observability ------------------------------------------------
+    def _actors(self):
+        return (self.edge,)
+
+    @property
+    def obs(self) -> Obs:
+        return self.edge.obs
+
+    @obs.setter
+    def obs(self, obs: Obs):
+        for actor in self._actors():
+            actor.obs = obs
 
     # -- state passthroughs (tests/benchmarks read these) ---------------
     @property
@@ -937,7 +1000,8 @@ class EdgeEngineBase:
             slot, int(rec.drafts[n]), pos_next, float(rec.betas[n]))
 
     def commit_speculative(self, spec: SpecDraft):
-        self.edge.commit_speculative(spec)
+        with self.obs.tracer.wall("edge.apply", args={"slots": [spec.slot]}):
+            self.edge.commit_speculative(spec)
 
     def spec_premise_holds(self, spec: SpecDraft, rec: PendingRound,
                            verdict: wire_mod.VerdictPayload) -> bool:
@@ -949,13 +1013,15 @@ class EdgeEngineBase:
 
     def unpack_verdict_slot(self, slot: int,
                             data: bytes) -> wire_mod.VerdictPayload:
-        return self.fmt.unpack_verdict(data,
-                                       codec=self.edge.slot_codec[slot])
+        with self.obs.tracer.wall("verdict.unpack", args={"slots": [slot]}):
+            return self.fmt.unpack_verdict(
+                data, codec=self.edge.slot_codec[slot])
 
     def unpack_verdict_batch(self, data: bytes):
         """Edge side: decode a cell's frame back to ascending-slot
         (slot, VerdictPayload) pairs."""
-        return self.fmt.unpack_verdict_batch(data, self.B)
+        with self.obs.tracer.wall("verdict.unpack"):
+            return self.fmt.unpack_verdict_batch(data, self.B)
 
     def apply_verdict_slot(self, slot: int,
                            verdict: wire_mod.VerdictPayload,
@@ -965,10 +1031,11 @@ class EdgeEngineBase:
         the slot's pages past the kept length.  ``shrink=False`` keeps
         the grown window — the event loop passes it when a confirmed
         speculative round's draft KV lives in those pages."""
-        emitted = self.edge.apply_verdict_slot(slot, verdict, rec)
-        self.out_tokens[slot].extend(emitted)
-        if self.paged and shrink:
-            self.alloc.shrink(slot, int(np.asarray(self.pos)[slot]))
+        with self.obs.tracer.wall("edge.apply", args={"slots": [slot]}):
+            emitted = self.edge.apply_verdict_slot(slot, verdict, rec)
+            self.out_tokens[slot].extend(emitted)
+            if self.paged and shrink:
+                self.alloc.shrink(slot, int(np.asarray(self.pos)[slot]))
         return emitted
 
 
@@ -987,15 +1054,18 @@ class EdgeCloudEngine(EdgeEngineBase):
                  method: MethodConfig, engine: EngineConfig = EngineConfig(),
                  channel: channel_mod.ChannelConfig =
                  channel_mod.ChannelConfig(),
-                 seed: int = 0):
+                 seed: int = 0, obs: Optional[Obs] = None):
         assert draft_cfg.vocab == target_cfg.vocab, "shared vocabulary"
         super().__init__(draft_cfg, draft_params, method, engine,
-                         channel, seed)
+                         channel, seed, obs)
         self.tc, self.tp = target_cfg, target_params
         self.cloud = CloudVerifyEngine(target_cfg, target_params, method,
-                                       engine, self.fmt, seed)
+                                       engine, self.fmt, seed, obs)
         self._target_stateful = self.cloud.stateful
         self.peer_stateful = self.cloud.stateful
+
+    def _actors(self):
+        return (self.edge, self.cloud)
 
     @property
     def tcache(self):
@@ -1039,16 +1109,24 @@ class EdgeCloudEngine(EdgeEngineBase):
         mask[list(packed)] = True
         if self.paged:
             self._push_tables()
-        payloads = wire_mod.unpack_drafts(
-            self.fmt, packed,
-            codecs={s: self.cloud.slot_codec[s] for s in packed})
+        payloads = self._unpack_drafts(packed)
         return self.cloud.verify(mask, payloads)
+
+    def _unpack_drafts(self, packed: Dict[int, bytes]
+                       ) -> Dict[int, wire_mod.DraftPayload]:
+        with self.obs.tracer.wall("cloud.unpack",
+                                  args={"slots": sorted(packed)}):
+            return wire_mod.unpack_drafts(
+                self.fmt, packed,
+                codecs={s: self.cloud.slot_codec[s] for s in packed})
 
     # -- per-slot verdict codec (the downlink mirror of the uplink
     #    negotiation; events.py and run_round both route through these)
     def pack_verdict_slot(self, slot: int,
                           v: wire_mod.VerdictPayload) -> bytes:
-        return self.fmt.pack_verdict(v, codec=self.cloud.slot_codec[slot])
+        with self.obs.tracer.wall("verdict.pack", args={"slots": [slot]}):
+            return self.fmt.pack_verdict(v,
+                                         codec=self.cloud.slot_codec[slot])
 
     # -- verdict BATCHING (one coded downlink frame per cell).  A frame
     #    serves many requests at once, so its codec is the LINK's
@@ -1062,7 +1140,9 @@ class EdgeCloudEngine(EdgeEngineBase):
         order — the deterministic frame order both ends rely on) into
         one downlink frame."""
         items = sorted(verdicts.items())
-        return self.fmt.pack_verdict_batch(items, self.B)
+        with self.obs.tracer.wall("verdict.pack",
+                                  args={"slots": [s for s, _ in items]}):
+            return self.fmt.pack_verdict_batch(items, self.B)
 
     # ------------------------------------------------------------------
     def run_round(self, verdict_groups: Optional[List[List[int]]] = None):
@@ -1092,9 +1172,7 @@ class EdgeCloudEngine(EdgeEngineBase):
 
         db = self.edge.draft(active)
         # --- the uplink: packed bytes cross, the cloud decodes ---------
-        payloads = wire_mod.unpack_drafts(
-            self.fmt, db.packed,
-            codecs={s: self.cloud.slot_codec[s] for s in db.packed})
+        payloads = self._unpack_drafts(db.packed)
         wire_bits_row = np.zeros((self.B,), np.float64)
         for slot, data in db.packed.items():
             wire_bits_row[slot] = wire_mod.packed_bits(data)

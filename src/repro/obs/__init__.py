@@ -7,20 +7,20 @@
     DecompTracker    — online Theorem-1 rejection decomposition and
                        conformal coverage telemetry (obs.decomp)
     Obs              — the bundle threaded through ServeSession /
-                       EventDrivenLoop / EdgeClient; ``NULL_OBS`` is the
-                       shared disabled instance (near-zero hot-path
-                       cost)
+                       EventDrivenLoop / EdgeClient and on into the
+                       engine (obs.handle); ``NULL_OBS`` is the shared
+                       disabled instance (near-zero hot-path cost)
 
 Load-bearing invariant (pinned by tests/test_fuzz_serve.py's obs axis
 and the tcp differential tests): observability moves NO tokens — every
-instrument only reads caller-supplied host values, so streams are
-bit-identical with obs on or off, over the simulator and over sockets.
+instrument only reads host values and the host's clock, never tokens,
+RNG state or scheduling, so streams are bit-identical with obs on or
+off, over the simulator and over sockets.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 from repro.obs.decomp import DecompTracker
+from repro.obs.handle import NULL_OBS, Obs
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                percentile, summary_stats)
 from repro.obs.trace import (CLOCK_MODELED, CLOCK_WALL, SpanTracer,
@@ -32,35 +32,6 @@ __all__ = [
     "percentile", "snapshot_topology", "span_names_by_clock",
     "summary_stats",
 ]
-
-
-class Obs:
-    """Tracer + metrics + (optional) Theorem-1 decomposition, as one
-    handle the serving loops thread through.  Construct with
-    ``Obs.on()`` for everything enabled, or default-construct (or use
-    ``NULL_OBS``) for the disabled bundle."""
-
-    def __init__(self, tracer: Optional[SpanTracer] = None,
-                 metrics: Optional[MetricsRegistry] = None,
-                 decomp: Optional[DecompTracker] = None):
-        self.tracer = tracer if tracer is not None \
-            else SpanTracer(enabled=False)
-        self.metrics = metrics if metrics is not None \
-            else MetricsRegistry(enabled=False)
-        self.decomp = decomp
-
-    @classmethod
-    def on(cls, decomp: Optional[DecompTracker] = None) -> "Obs":
-        return cls(SpanTracer(enabled=True), MetricsRegistry(enabled=True),
-                   decomp)
-
-    @property
-    def enabled(self) -> bool:
-        return (self.tracer.enabled or self.metrics.enabled
-                or self.decomp is not None)
-
-
-NULL_OBS = Obs()
 
 
 def snapshot_topology(metrics: MetricsRegistry, topo) -> None:

@@ -2,24 +2,37 @@
 
 The serving stack runs on two kinds of time: the discrete-event
 simulator's MODELED clock (``ServeSession`` / ``EventDrivenLoop``
-virtual seconds) and the socket runner's WALL clock
-(``time.perf_counter`` deltas in ``serve.net``).  A trace of one
-tcp-vs-sim run therefore carries both: the tracer maps each clock to
-its own Chrome-trace *process* (pid), so Perfetto shows the modeled
-round phases (draft / uplink / verify / downlink) and the measured RPC
-spans side by side on independent timelines.
+virtual seconds) and the WALL clock (``time.perf_counter``: the socket
+runner's RPC spans in ``serve.net``, and the engine's host work).  A
+trace of one tcp-vs-sim run therefore carries both: the tracer maps
+each clock to its own Chrome-trace *process* (pid), so Perfetto shows
+the modeled round phases (draft / uplink / verify / downlink) and the
+measured spans side by side on independent timelines.
+
+Two ways in:
+
+  * ``span`` / ``begin`` / ``end`` / ``instant`` take caller-supplied
+    timestamps on either clock (the serving loops' modeled phases);
+  * ``wall(name)`` is a scoped span on the wall clock: it reads
+    ``time.perf_counter()`` at entry and exit, records the innermost
+    open span of its lane as its parent, and opens a
+    ``jax.profiler.TraceAnnotation`` of the same name, so in a profiler
+    trace the span sits on the host plane on the device ops' clock.
 
 Design constraints, in order:
 
-  * ZERO PERTURBATION — the tracer only ever receives caller-supplied
-    timestamps and never reads a clock, an RNG or any token-affecting
-    state itself.  Token streams are bit-identical with tracing on or
-    off (tests/test_fuzz_serve.py sweeps exactly this).
+  * ZERO PERTURBATION — spans read the host clock and caller-supplied
+    values only, never an RNG, a token or scheduling state, and nothing
+    reads a span back into the serving path.  Token streams are
+    bit-identical with tracing on or off (tests/test_fuzz_serve.py
+    sweeps exactly this).
   * near-zero cost disabled — every public method starts with one
-    ``enabled`` check and allocates nothing when off.
+    ``enabled`` check and allocates nothing when off; ``wall`` then
+    returns one shared no-op context that reads no clock and opens no
+    annotation.
   * deterministic ids — span ids and thread ids are monotone counters
     in emission/first-use order, so the same run produces the same
-    trace byte for byte.
+    trace structure (wall-clock times differ run to run by nature).
 
 Export is the Chrome trace-event JSON format (the ``traceEvents``
 array of ``"ph": "X"`` complete events plus ``"M"`` metadata naming
@@ -29,7 +42,10 @@ the processes/threads), which https://ui.perfetto.dev and
 from __future__ import annotations
 
 import json
+import time
 from typing import Dict, List, Optional, Set, Tuple
+
+import jax
 
 __all__ = ["SpanTracer", "CLOCK_MODELED", "CLOCK_WALL",
            "span_names_by_clock"]
@@ -37,6 +53,45 @@ __all__ = ["SpanTracer", "CLOCK_MODELED", "CLOCK_WALL",
 CLOCK_MODELED = "modeled"
 CLOCK_WALL = "wall"
 _CLOCK_PIDS = {CLOCK_MODELED: 1, CLOCK_WALL: 2}
+HOST_LANE = "host"        # the lane ``wall`` spans nest on by default
+
+
+class _NullSpan:
+    """The disabled ``wall`` span: one shared instance, no clock read,
+    no annotation."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _WallSpan:
+    __slots__ = ("tracer", "name", "tid", "args", "annotation")
+
+    def __init__(self, tracer: "SpanTracer", name: str, tid: str,
+                 args: Optional[dict]):
+        self.tracer, self.name, self.tid, self.args = tracer, name, tid, args
+
+    def __enter__(self):
+        self.annotation = jax.profiler.TraceAnnotation(self.name)
+        self.annotation.__enter__()
+        stack = self.tracer._stacks.get((CLOCK_WALL, self.tid))
+        parent = stack[-1]["id"] if stack else -1
+        self.tracer.begin(self.name, time.perf_counter(), clock=CLOCK_WALL,
+                          tid=self.tid,
+                          args={"parent": parent, **(self.args or {})})
+        return self
+
+    def __exit__(self, *exc):
+        self.tracer.end(time.perf_counter(), clock=CLOCK_WALL, tid=self.tid)
+        self.annotation.__exit__(*exc)
+        return False
 
 
 class SpanTracer:
@@ -119,6 +174,18 @@ class SpanTracer:
                      **(args or {})}})
         return top["id"]
 
+    def wall(self, name: str, tid: str = HOST_LANE,
+             args: Optional[dict] = None):
+        """Scoped wall-clock span: ``with tracer.wall("edge.pack"):``.
+        Enabled, it records [entry, exit] of ``time.perf_counter()`` on
+        the wall lane ``tid`` with ``args`` and its parent's id
+        (``args["parent"]``, -1 at the top), and mirrors itself into the
+        profiler as a ``TraceAnnotation``.  Disabled, it returns a shared
+        no-op context."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return _WallSpan(self, name, tid, args)
+
     def instant(self, name: str, t_s: float, clock: str = CLOCK_MODELED,
                 tid: str = "main", args: Optional[dict] = None) -> int:
         """A zero-duration marker (speculation hit/miss/abort...)."""
@@ -137,6 +204,20 @@ class SpanTracer:
     @property
     def n_events(self) -> int:
         return len(self._events)
+
+    def wall_spans(self, tid: str = HOST_LANE) -> List[tuple]:
+        """The closed ``wall`` spans of lane ``tid`` as (name, t0_s,
+        t1_s, id, parent, args) on the ``time.perf_counter`` clock, in
+        closing order."""
+        pid, lane = _CLOCK_PIDS[CLOCK_WALL], self._tids.get((CLOCK_WALL, tid))
+        out = []
+        for e in self._events:
+            if e["ph"] == "X" and e["pid"] == pid and e["tid"] == lane:
+                a = e["args"]
+                t0 = e["ts"] / 1e6
+                out.append((e["name"], t0, t0 + e["dur"] / 1e6, a["id"],
+                            a.get("parent", -1), a))
+        return out
 
     def chrome_trace(self) -> dict:
         assert not any(self._stacks.values()), \

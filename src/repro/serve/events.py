@@ -226,7 +226,13 @@ class RoundStateMachine:
         emitted = self.eng.apply_verdict_slot(slot, verdict, rec,
                                               shrink=not hit)
         req.n_rounds += 1
+        n0 = req.n_tokens
         finished = req.add_tokens(emitted, now)
+        # tokens the request took (EOS or its length limit may cut the
+        # emission short): with rounds_applied, tokens per slot-round
+        self.obs.metrics.counter("serve.rounds_applied").inc()
+        self.obs.metrics.counter("serve.tokens_emitted").inc(
+            req.n_tokens - n0)
         if finished:
             self.sched.complete(req, now)
             self.eng.release_slot(slot)
@@ -365,7 +371,8 @@ class EventDrivenLoop:
         rec = self.rsm.draft(slot)
         t_done = self.now + self._dur_slm(rec.t_slm)
         self.obs.tracer.span("draft", self.now, t_done,
-                             tid=f"slot{slot}")
+                             tid=f"slot{slot}",
+                             args={"rid": self.slots[slot].req.rid})
         self._push(t_done, EDGE_DONE, (slot, rec))
 
     def _on_edge_done(self, data):
@@ -377,7 +384,7 @@ class EventDrivenLoop:
         ctx.req.uplink_wait_s += tx.wait_s
         self.obs.tracer.span("uplink", self.now, tx.arrive_s,
                              tid=f"slot{slot}",
-                             args={"wait_s": tx.wait_s,
+                             args={"rid": ctx.req.rid, "wait_s": tx.wait_s,
                                    "bits": rec.wire_bits})
         self._push(tx.arrive_s, UPLINK_ARRIVE, slot)
         # the edge device is idle until the verdict returns: draft ahead
@@ -385,7 +392,8 @@ class EventDrivenLoop:
         if spec is not None:
             ctx.spec_ready_s = self.now + self._dur_slm(spec.round.t_slm)
             self.obs.tracer.span("spec_draft", self.now, ctx.spec_ready_s,
-                                 tid=f"slot{slot}")
+                                 tid=f"slot{slot}",
+                                 args={"rid": ctx.req.rid})
 
     # -- uplink / cloud -------------------------------------------------
     def _on_uplink_arrive(self, slot: int):
@@ -403,7 +411,9 @@ class EventDrivenLoop:
         done = self.now + self._dur_llm(vb.t_llm)
         self.cloud_busy_until = done
         self.obs.tracer.span("verify", self.now, done, tid="cloud",
-                             args={"n_slots": len(batch)})
+                             args={"n_slots": len(batch),
+                                   "rids": [self.slots[s].req.rid
+                                            for s in batch]})
         self.obs.metrics.histogram(
             "serve.verify.batch_size",
             bounds=(1, 2, 4, 8, 16, 32)).observe(len(batch))

@@ -394,7 +394,7 @@ class EdgeClient:
         self.io_timeout_s = io_timeout_s
         self.engine = EdgeTransportEngine(
             draft_cfg, draft_params, method, engine,
-            channel_mod.ChannelConfig(), seed)
+            channel_mod.ChannelConfig(), seed, obs=self.obs)
         assert not self.engine.edge.stateful, \
             "tcp transport serves attention-only draft models"
         self.engine.admit_cb = self._send_admit

@@ -8,7 +8,7 @@ Two policies:
               Orca/vLLM discipline).
   static      — slots are only refilled when the WHOLE batch has
               drained: classic static batching, kept as the baseline
-              the serve_load benchmark compares against.
+              continuous batching is compared against.
 
 Admission control: the waiting room holds at most ``queue_cap``
 requests; arrivals beyond that are rejected (the per-method rejection

@@ -175,6 +175,8 @@ class ServeSession:
         # counters and the Theorem-1 decomposition never feed back into
         # scheduling or tokens (NULL_OBS = everything disabled)
         self.obs = obs if obs is not None else NULL_OBS
+        if obs is not None:
+            engine.obs = obs      # one handle: loop spans + engine spans
         self.n_spec_hits = 0
         self.n_spec_misses = 0
         # the topology IS the scheduler: one cell degenerates to the

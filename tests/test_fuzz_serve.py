@@ -35,7 +35,7 @@ from repro import configs
 from repro.core import EdgeCloudEngine, EngineConfig, MethodConfig
 from repro.core.channel import ChannelConfig, SharedUplink
 from repro.models import init_params
-from repro.obs import DecompTracker, Obs
+from repro.obs import CLOCK_WALL, DecompTracker, Obs, span_names_by_clock
 from repro.serve import (CellTopology, EventQueue, Request, ServeConfig,
                          ServeSession, TraceConfig, poisson_trace)
 
@@ -118,6 +118,10 @@ def _run(pair, trace_cfg, overrides, channel, cells, pipe, codec, batch,
     assert np.isfinite(rep.downlink_utilization)
     if obs is not None:
         assert obs.tracer.n_events > 0
+        # the engine's wall spans ride the same handle as the loop's
+        wall = span_names_by_clock(obs.tracer.chrome_trace())[CLOCK_WALL]
+        assert {"edge.step", "cloud.step", "edge.pack",
+                "cloud.upload"} <= wall
         if obs.decomp is not None:
             ok, err = obs.decomp.reconcile()
             assert ok, f"thm1 telemetry failed to reconcile ({err})"

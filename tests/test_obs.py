@@ -159,6 +159,86 @@ def test_tracer_rejects_unknown_clock():
 
 
 # ----------------------------------------------------------------------
+# Scoped wall spans (engine host work, mirrored into the profiler)
+# ----------------------------------------------------------------------
+def test_wall_span_disabled_reads_no_clock_and_no_annotation(monkeypatch):
+    import time
+
+    def boom(*a, **k):
+        raise AssertionError("a disabled wall span touched the clock or "
+                             "the profiler")
+    monkeypatch.setattr(time, "perf_counter", boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    t = SpanTracer(enabled=False)
+    a, b = t.wall("edge.step"), NULL_OBS.tracer.wall("cloud.step",
+                                                     args={"slots": [0]})
+    assert a is b                  # one shared no-op context
+    with a, b:
+        pass
+    assert t.n_events == 0 and t.wall_spans() == []
+
+
+def test_wall_span_records_parents_args_and_exports(monkeypatch):
+    opened = []
+
+    class Ann:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Ann)
+    t = SpanTracer()
+    with t.wall("cloud.verify", args={"slots": [0, 2]}):
+        with t.wall("cloud.upload", args={"slots": [0, 2]}):
+            pass
+        with t.wall("cloud.step"):
+            pass
+    with t.wall("verdict.pack"):
+        pass
+    assert opened == ["cloud.verify", "cloud.upload", "cloud.step",
+                      "verdict.pack"]
+    spans = {name: (t0, t1, sid, parent, args)
+             for name, t0, t1, sid, parent, args in t.wall_spans()}
+    outer = spans["cloud.verify"]
+    assert outer[3] == -1 and outer[4]["slots"] == [0, 2]
+    for inner in ("cloud.upload", "cloud.step"):
+        t0, t1, _, parent, _ = spans[inner]
+        assert parent == outer[2]
+        assert outer[0] <= t0 <= t1 <= outer[1]
+    assert spans["cloud.upload"][4]["slots"] == [0, 2]
+    assert spans["verdict.pack"][3] == -1
+    assert spans["verdict.pack"][0] >= outer[1]
+    names = span_names_by_clock(t.chrome_trace())
+    assert names[CLOCK_WALL] == set(spans)
+
+
+def test_wall_span_lands_on_the_profiler_host_plane(tmp_path):
+    import glob
+    from jax.profiler import ProfileData
+    t = SpanTracer()
+    x = jax.numpy.ones((8, 8))
+    jax.block_until_ready(x @ x)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.wall("edge.fetch"):
+            with t.wall("edge.step"):
+                jax.block_until_ready(x @ x)
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert files
+    host = {e.name for f in files
+            for plane in ProfileData.from_file(f).planes
+            if plane.name.startswith("/host")
+            for line in plane.lines for e in line.events}
+    assert {"edge.fetch", "edge.step"} <= host
+
+
+# ----------------------------------------------------------------------
 # Theorem-1 decomposition on synthetic round metrics
 # ----------------------------------------------------------------------
 def _synthetic_round(rng, B=2, L=3, V=8):
@@ -244,17 +324,27 @@ def pair():
     return dc, dp, tc, tp
 
 
-def _serve(pair, obs):
+ENGINE_SPANS = {"edge.prep", "edge.step", "edge.fetch", "edge.pack",
+                "cloud.unpack", "cloud.upload", "cloud.step",
+                "cloud.commit", "verdict.pack", "verdict.unpack",
+                "edge.admit", "cloud.admit"}
+
+
+def _serve(pair, obs, pipeline="lockstep", eng_hook=None):
     dc, dp, tc, tp = pair
     eng = EdgeCloudEngine(
         dc, dp, tc, tp, METHOD,
-        EngineConfig(L_max=3, collect_theory=obs is not None),
+        EngineConfig(L_max=3, collect_theory=obs is not None
+                     and obs.decomp is not None),
         ChannelConfig(), seed=0)
+    if eng_hook is not None:
+        eng_hook(eng)
     trace = poisson_trace(TraceConfig(
         n_requests=4, rate_rps=8.0, prompt_len=10, min_new_tokens=3,
         max_new_tokens=6, vocab=tc.vocab, seed=5))
     sess = ServeSession(eng, ServeConfig(
-        max_batch=2, cache_len=64, t_slm_s=0.01, t_llm_s=0.02), obs=obs)
+        max_batch=2, cache_len=64, t_slm_s=0.01, t_llm_s=0.02,
+        pipeline=pipeline), obs=obs)
     rep = sess.run_trace(trace)
     return {r.rid: tuple(r.tokens) for r in rep.requests}, sess
 
@@ -270,6 +360,13 @@ def test_serve_obs_zero_perturbation_and_reconcile(pair):
     names = span_names_by_clock(obs.tracer.chrome_trace())
     assert {"draft", "uplink", "verify",
             "downlink"} <= names[CLOCK_MODELED]
+    # the session handed its Obs to the engine: the host work between
+    # the step programs is spanned on the wall clock
+    assert sess.engine.obs is obs
+    assert ENGINE_SPANS <= names[CLOCK_WALL]
+    # edge.step / cloud.step are the t_slm / t_llm intervals, one per call
+    steps = [s for s in obs.tracer.wall_spans() if s[0] == "edge.step"]
+    assert len(steps) == sess.n_rounds
     ok, err = obs.decomp.reconcile()
     assert ok, f"thm1 telemetry failed to reconcile (max err {err})"
     cov = obs.decomp.coverage()
@@ -282,3 +379,53 @@ def test_serve_obs_zero_perturbation_and_reconcile(pair):
     assert snap["counters"]["serve.cell0.uplink.msgs"] == \
         sess.topo.cells[0].uplink.n_msgs
     json.dumps(snap)       # the --metrics-out artifact is plain JSON
+
+
+def test_engine_counters_match_the_payloads(pair):
+    """wire.* and edge.rows_* equal counts made by hand from what a
+    pipelined served trace put on the wire; serve.rounds_applied and
+    serve.tokens_emitted equal the requests' own rounds and tokens."""
+    seen = {"payloads": [], "rows": 0, "calls": 0}
+
+    def hook(eng):
+        draft_slots, spec = eng.draft_slots, eng.draft_speculative_slot
+        verify_slots = eng.verify_slots
+
+        def w_draft(slots):
+            seen["calls"] += 1
+            seen["rows"] += len(slots)
+            return draft_slots(slots)
+
+        def w_spec(slot, rec):
+            out = spec(slot, rec)
+            if out is not None:
+                seen["calls"] += 1
+                seen["rows"] += 1
+            return out
+
+        def w_verify(packed):
+            seen["payloads"] += [
+                eng.fmt.unpack_draft(b, codec=eng.cloud.slot_codec[s])
+                for s, b in packed.items()]
+            return verify_slots(packed)
+        eng.draft_slots, eng.draft_speculative_slot = w_draft, w_spec
+        eng.verify_slots = w_verify
+        seen["eng"] = eng
+
+    ref, _ = _serve(pair, None, pipeline="pipelined")
+    obs = Obs.on()
+    streams, sess = _serve(pair, obs, pipeline="pipelined", eng_hook=hook)
+    assert streams == ref
+    eng, payloads = seen["eng"], seen["payloads"]
+    c = obs.metrics.snapshot()["counters"]
+    assert c["wire.payloads"] == len(payloads) > 0
+    assert c["wire.live_drafts"] == sum(len(p.tokens) for p in payloads)
+    assert c["wire.support_tokens"] == sum(
+        len(sup) for p in payloads for sup in p.supports)
+    assert c["edge.rows_requested"] == seen["rows"]
+    assert c["edge.rows_computed"] == seen["calls"] * eng.B
+    reqs = sess.topo.finished
+    assert c["serve.rounds_applied"] == sum(r.n_rounds for r in reqs)
+    assert c["serve.tokens_emitted"] == sum(r.n_tokens for r in reqs)
+    names = span_names_by_clock(obs.tracer.chrome_trace())
+    assert ENGINE_SPANS | {"edge.apply"} <= names[CLOCK_WALL]
