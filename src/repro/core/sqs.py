@@ -17,7 +17,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.slq import lattice_quantize
+from repro.core.slq import (from_order_key, lattice_quantize,
+                            nth_largest_key, order_key)
 
 
 class SQSResult(NamedTuple):
@@ -42,7 +43,8 @@ def sparsify_topk(q, K: int, ell: int) -> SQSResult:
     """K-SQS: keep the K largest-probability tokens (fixed K)."""
     V = q.shape[-1]
     K = min(K, V)
-    kth = jax.lax.top_k(q, K)[0][..., -1:]               # (B, 1)
+    n = jnp.full(q.shape[:-1] + (1,), K, jnp.int32)
+    kth = from_order_key(nth_largest_key(order_key(q), n))   # (B, 1)
     mask = q >= kth
     # ties could admit > K entries: break by index (keep first K)
     over = jnp.cumsum(mask.astype(jnp.int32), axis=-1) <= K

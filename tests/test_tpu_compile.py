@@ -11,6 +11,7 @@ one process at a time may load the TPU library, and every xdist worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,3 +84,16 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
+
+
+@pytest.mark.parametrize("fn", ["sparsify_threshold", "sparsify_topk"])
+def test_sqs_core_compiles_without_sort_for_v5e(one_chip, fn):
+    """The jnp SQS path (the draft step's, ``use_kernels=False``) at
+    Qwen2.5-3B's vocabulary: after the TPU compiler's passes, no sort."""
+    from repro.core import sqs
+    f = {"sparsify_threshold": lambda q: sqs.sparsify_threshold(q, 1e-3, 100),
+         "sparsify_topk": lambda q: sqs.sparsify_topk(q, 64, 100)}[fn]
+    q = jax.ShapeDtypeStruct((B, V), jnp.float32, sharding=one_chip)
+    text = jax.jit(f).lower(q).compile().as_text()
+    assert not re.search(r"\b(sort|topk)\(", text)
+    assert not re.search(r'custom_call_target="[^"]*top_?k', text, re.I)
