@@ -4,13 +4,16 @@ metrics, decide ``correct``, and print the result.
 A cell ``<name>`` of BENCHMARK.json names a configuration and a traffic
 mix; its files are
 
-    bench/configs/<config>.json     sizes as run, source, cuts, draft rule
+    bench/configs/<config>.json     sizes as run, source, cuts, model
+                                    kind, draft rule
+    bench/kinds/<kind>.py           the kind's weights, plain reference
+                                    and counts (harness.kinds)
     bench/traffic/<traffic>.json    lengths, rate, burst (harness.traffic)
     bench/workloads/<name>.json     method, engine, channel, slots,
                                     audit size and the limits of `correct`
     bench/metrics/<metric>.py       one reader per metric: read(rec)
 
-so a later cell, mix or metric is added by adding files.
+so a later cell, mix, model kind or metric is added by adding files.
 """
 from __future__ import annotations
 
@@ -24,6 +27,8 @@ import tempfile
 import time
 
 import numpy as np
+
+from harness import kinds, progspans
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -42,13 +47,13 @@ def load(root: str, name: str) -> dict:
                          f"BENCHMARK.json (known: {sorted(wl)})")
     w = wl[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = _json(os.path.relpath(os.path.join(root, conf["file"]), BENCH))
+    kinds.of_config(config)         # a missing or unknown kind fails here
 
     def applies(m):
         return name in m.get("workloads", [name])
 
-    return {"name": name, "chips": w["chips"],
-            "config": _json(os.path.relpath(os.path.join(root, conf["file"]),
-                                            BENCH)),
+    return {"name": name, "chips": w["chips"], "config": config,
             "traffic": _json("traffic", w["traffic"] + ".json"),
             "cell": _json("workloads", name + ".json"),
             "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
@@ -65,10 +70,16 @@ def reader(metric: str):
 
 
 class Records:
-    """What the metric readers read: the run's records and the cell."""
+    """What the metric readers read: the run's records and the cell.
+
+    A traced run adds the program's own readings: ``spans``, its wall
+    spans that start in the window (harness.progspans); ``counters``, its
+    counters' increments over the window; ``scope_dev_s[module][scope]``,
+    each step program's device seconds by named scope (harness.tracefile).
+    Each is None in an untraced run."""
 
     def __init__(self, run, spec, setup_s, trace, peaks):
-        self.tc, self.dc = run.tc, run.dc
+        self.target, self.draft = run.target, run.draft
         self.L = run.L
         self.cell, self.traffic = spec["cell"], spec["traffic"]
         self.t_open, self.t_close = run.t_open, run.t_close
@@ -82,6 +93,12 @@ class Records:
         self.setup_s = setup_s
         self.trace = trace
         self.peaks = peaks
+        obs = run.obs
+        self.spans = None if obs is None else progspans.in_window(
+            obs.tracer.wall_spans(), run.t_open, run.t_close)
+        self.counters = None if obs is None else progspans.deltas(
+            run.obs_open, run.obs_close)
+        self.scope_dev_s = trace["scope_dev_s"] if trace else None
 
     def in_window(self):
         return [d for d in self.deliveries
@@ -112,21 +129,23 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
     from harness import check, counts, session, tracefile
     from harness import traffic as traffic_mod
     from harness.clocks import CompileClock
+    from repro.obs import Obs
     clock = CompileClock()
-    cfg, cell, tr = spec["config"], spec["cell"], spec["traffic"]
+    cell, tr = spec["cell"], spec["traffic"]
     s_t, s_d = (int(x) for x in
                 np.random.default_rng([seed, 1]).integers(0, 2**31 - 1, 2))
-    tc, dc, tw, dw = session.build_models(cfg["model"], cfg["draft"],
-                                          s_t, s_d)
+    target, draft = kinds.build(spec["config"], s_t, s_d)
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
-    run = session.ServedRun(cell, tr, tc, dc, tw, dw, seed, seconds, clock,
+    run = session.ServedRun(cell, tr, target, draft, seed, seconds, clock,
                             trace_dir=trace_dir,
-                            trace_seconds=cell["trace_seconds"], fault=fault)
+                            trace_seconds=cell["trace_seconds"], fault=fault,
+                            obs=Obs.on() if trace else None)
     t_built = time.perf_counter()
     run.warm()
     t_warm = time.perf_counter()
     _memory("after warm-up", devs)
-    arrivals = traffic_mod.make_trace(tr, tc.vocab, seed, tr["n_requests"])
+    arrivals = traffic_mod.make_trace(tr, target.cfg.vocab, seed,
+                                      tr["n_requests"])
     run.run(arrivals)
     _memory("at the close", devs)
     dev = devs[0]
@@ -141,7 +160,10 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
         if dev.platform == "tpu" else None
     red = None
     if trace_dir:
-        red = tracefile.reduce(tracefile.load(trace_dir), run.trace_span)
+        red = tracefile.reduce(
+            tracefile.load(trace_dir), run.trace_span,
+            {m: tracefile.hlo_op_names(t)
+             for m, t in run.program_texts().items()})
         shutil.rmtree(trace_dir, ignore_errors=True)
     rec = Records(run, spec, setup_s, red, peaks)
     _diagnostics(run, rec)
@@ -152,7 +174,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
     del run
     gc.collect()
     t_ref = time.perf_counter()
-    nums = check.compare(rounds, positions, tc, dc, tw, dw, cell["method"],
+    nums = check.compare(rounds, positions, target, draft, cell["method"],
                          cell["engine"], quant=tuple(quant))
     rows, ok = check.verdict(nums, cell["limits"], cell["audit"])
     print(f"reference comparison: {nums['rounds']} rounds and "
@@ -239,6 +261,13 @@ def _diagnostics(run, rec):
     if rec.trace:
         print(f"trace lines: {rec.trace['lines']}", flush=True)
         print(f"trace programs: {rec.trace['programs']}", flush=True)
+        print(f"device seconds by named scope: {rec.scope_dev_s}",
+              flush=True)
+    if rec.spans is not None:
+        print(f"program spans: {len(rec.spans)} in the window, covering "
+              f"{progspans.coverage(rec.spans, rec.calls)} of the engine "
+              f"calls' wall time off the step programs; counters "
+              f"{rec.counters}", flush=True)
     ks = [np.count_nonzero(np.asarray(r["draft"][1]), axis=-1).tolist()
           for r in run.audit if r is not None]
     print(f"audited q-hat supports (b > 0) per position: {ks}", flush=True)

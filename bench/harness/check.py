@@ -1,8 +1,9 @@
 """The comparison that decides ``correct``.
 
-It compares what the timed path produced with the plain reference
-(harness.reference), run over each request's own prompt and served
-tokens, on two samples from the window (harness.session):
+It compares what the timed path produced with the plain reference (the
+``logits`` of each model's kind, harness.kinds), run over each request's
+own prompt and served tokens, on two samples from the window
+(harness.session):
 
 * every served position: the first row of each real draft step's q and
   each verify call's p, at the position of the request's last served
@@ -65,8 +66,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import reference
-
 ROW_BUCKET = 32     # rows are padded to a multiple: few programs
 MIN_POSITIONS = 20  # served positions a run must compare, both models
 
@@ -111,8 +110,7 @@ class HeadSpace:
     lies outside it comes from the head's own weights or the logits'
     rounding."""
 
-    def __init__(self, w: dict):
-        W = w["head"].T if "head" in w else w["embed"]
+    def __init__(self, W):
         with jax.default_matmul_precision("highest"):
             self.Wc, self.chol = _head_space(W)
 
@@ -216,17 +214,19 @@ def audit_positions(run) -> dict:
     return out
 
 
-def _ref_rows(cfg, w, seq, rows, quant=None):
-    """Reference logits at ``rows`` of the teacher-forced ``seq``."""
+def _ref_rows(m, seq, rows, quant=None):
+    """Reference logits of model ``m`` at ``rows`` of the teacher-forced
+    ``seq``."""
     n = len(rows)
     padded = list(rows) + [rows[-1]] * (-n % ROW_BUCKET)
-    return reference.logits(cfg, w, seq, padded, quant=quant)[:n]
+    return m.kind.logits(m.cfg, m.w, seq, padded, quant=quant)[:n]
 
 
-def compare(rounds, positions, tc, dc, tw, dw, method: dict, engine: dict,
+def compare(rounds, positions, target, draft, method: dict, engine: dict,
             quant=()) -> dict:
     """Numbers compared for the audited ``rounds`` (audit_rounds) and
-    served ``positions`` (audit_positions).  For each lower weight
+    served ``positions`` (audit_positions) of the ``target`` and
+    ``draft`` models (harness.kinds.Model).  For each lower weight
     precision in ``quant`` the control's readings of the model numbers
     are returned under ``control[mode]``."""
     T, L, ell = engine["temperature"], engine["L_max"], method["ell"]
@@ -238,7 +238,8 @@ def compare(rounds, positions, tc, dc, tw, dw, method: dict, engine: dict,
                 "rounds": len(rounds), "positions": 0})
     ctl = {m: {n: 0.0 for n in model_nums} for m in quant}
 
-    space = {"target": HeadSpace(tw), "draft": HeadSpace(dw)}
+    space = {who: HeadSpace(m.kind.head(m.cfg, m.w))
+             for who, m in (("target", target), ("draft", draft))}
 
     def widest(into, who, readings):
         for name, r in zip(("_gap", "_rest", "_tv"), readings):
@@ -254,8 +255,8 @@ def compare(rounds, positions, tc, dc, tw, dw, method: dict, engine: dict,
     for rid in sorted(positions):
         d = positions[rid]
         seq = np.concatenate([d["prompt"], d["served"]]).astype(np.int32)
-        for who, kind, cfg, w in (("draft", "draft", dc, dw),
-                                  ("target", "verify", tc, tw)):
+        for who, kind, m in (("draft", "draft", draft),
+                             ("target", "verify", target)):
             keep = [(pos, row) for pos, x, row in d[kind]
                     if pos < len(seq) and seq[pos] == x]
             out["state_faults"] += len(d[kind]) - len(keep)
@@ -265,8 +266,8 @@ def compare(rounds, positions, tc, dc, tw, dw, method: dict, engine: dict,
             rows = [pos for pos, _ in keep]
             part = seq[:max(rows) + 1]
             read(who, np.stack([row for _, row in keep]),
-                 _ref_rows(cfg, w, part, rows),
-                 {m: _ref_rows(cfg, w, part, rows, m) for m in quant})
+                 _ref_rows(m, part, rows),
+                 {q: _ref_rows(m, part, rows, q) for q in quant})
 
     # -- the audited rounds, all L+1 positions
     for r in rounds:
@@ -278,13 +279,13 @@ def compare(rounds, positions, tc, dc, tw, dw, method: dict, engine: dict,
             continue
         rows = np.arange(pos, pos + L + 1)
         # -- models against the reference --------------------------------
-        for who, cfg, w, toks, prob in (
-                ("draft", dc, dw, r["d_tok"][:L], r["q"]),
-                ("target", tc, tw, r["v_tokens"][1:], r["p"])):
+        for who, m, toks, prob in (
+                ("draft", draft, r["d_tok"][:L], r["q"]),
+                ("target", target, r["v_tokens"][1:], r["p"])):
             full = np.concatenate([seq[:pos + 1], toks])
-            read(who, prob, reference.logits(cfg, w, full, rows),
-                 {m: reference.logits(cfg, w, full, rows, quant=m)
-                  for m in quant})
+            read(who, prob, m.kind.logits(m.cfg, m.w, full, rows),
+                 {q: m.kind.logits(m.cfg, m.w, full, rows, quant=q)
+                  for q in quant})
         # -- SQS: support rule and lattice counts -------------------------
         for i in range(L + 1):
             q = r["q"][i]

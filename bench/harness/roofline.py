@@ -1,15 +1,18 @@
-"""Roofline shares and whole-window FLOPs from the run's records, the
-counts of harness.counts and the trace reduction of harness.tracefile."""
+"""Roofline shares and whole-window FLOPs from the run's records, each
+model's kind's counts (harness.counts states the model) and the trace
+reduction of harness.tracefile."""
 from __future__ import annotations
 
 from harness import counts
 
-_CALL = {"draft": counts.draft_call, "spec": counts.draft_call,
-         "verify": counts.verify_call}
+_CALL = {"draft": "draft_call", "spec": "draft_call",
+         "verify": "verify_call"}
 
 
-def _cfg(rec, kind):
-    return rec.tc if kind == "verify" else rec.dc
+def _count(rec, call):
+    """(flops, bytes) of one recorded engine call, by its model's kind."""
+    m = rec.target if call[0] == "verify" else rec.draft
+    return getattr(m.kind, _CALL[call[0]])(m.cfg, call[5], rec.L)
 
 
 def share(rec, kinds, module: str):
@@ -22,14 +25,13 @@ def share(rec, kinds, module: str):
     calls = [c for c in rec.calls if c[0] in kinds]     # the traced window
     if not prog or not prog["n"] or not calls:
         return None
-    least = [counts.least_time(*_CALL[c[0]](_cfg(rec, c[0]), c[5], rec.L),
-                               rec.peaks)[0] for c in calls]
+    least = [counts.least_time(*_count(rec, c), rec.peaks)[0] for c in calls]
     return (sum(least) / len(least)) / (prog["dev_s"] / prog["n"]) * 100.0
 
 
 def window_flops(rec) -> float:
-    f = sum(_CALL[c[0]](_cfg(rec, c[0]), c[5], rec.L)[0] for c in rec.calls)
+    f = sum(_count(rec, c)[0] for c in rec.calls)
     for _, S in rec.admissions:
-        f += counts.prefill_call(rec.tc, S)[0] + counts.prefill_call(rec.dc,
-                                                                      S)[0]
+        for m in (rec.target, rec.draft):
+            f += m.kind.prefill_call(m.cfg, S)[0]
     return float(f)

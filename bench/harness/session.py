@@ -8,8 +8,9 @@ driven through its public API, with a measured window on the wall clock.
 
 What this module adds around it, all from the benchmark's side:
 
-* set-up: weights from the seed (harness.weights), every prefill bucket,
-  the draft, speculative-draft, verify and verdict paths warmed once;
+* set-up: weights from the seed (each model's kind, harness.kinds),
+  every prefill bucket, the draft, speculative-draft, verify and verdict
+  paths warmed once;
 * the window: it opens at the first engine call after every slot has
   been filled and one delivery per slot has been made since, and closes at the first engine call ``seconds`` of wall time
   later.  A traced run profiles its whole window, which then lasts the
@@ -21,7 +22,10 @@ What this module adds around it, all from the benchmark's side:
   finishes at its next delivery, so the run ends within a round or two;
 * records for the metrics: one line per engine call (wall span, the
   program's own t_slm / t_llm, slots, context lengths), the uplink
-  counters of the program's ``SharedLink`` at open and close;
+  counters of the program's ``SharedLink`` at open and close; in a
+  traced run, the program's own ``Obs`` (handed to its ``ServeSession``)
+  with its counters at open and close, and the two step programs'
+  compiled text (``program_texts``), which names their ops' scopes;
 * the audit for ``correct``, in two parts:
   - every served position: for every real draft call and every verify
     call in the window, the first row of the step's distribution (the
@@ -48,12 +52,19 @@ import jax
 import numpy as np
 
 from harness import traffic as traffic_mod
-from harness import weights as weights_mod
 
 # serving settings that no cell varies yet
 QUEUE_CAP = 32          # requests waiting for a slot before arrivals are refused
 N_RADIO_CELLS = 1       # one shared uplink and downlink
 SETTLE_ROUNDS = 1       # deliveries per slot between the slots filling and the window
+
+
+def _abstract(x):
+    """Shape, dtype and, for an array placed on a device, its sharding:
+    what the jit cache keys a call on."""
+    committed = getattr(x, "committed", False)
+    return jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                sharding=x.sharding if committed else None)
 
 
 # -- device-side row slicers (compiled in set-up, reused in the window) --
@@ -80,27 +91,17 @@ def _verify_rows(tokens_in, pos, q_hat, live, key, p, n_accept, new_token,
             p[row], n_accept[row], new_token[row])
 
 
-def build_models(model_cfg, draft_rule, seed_t: int, seed_d: int):
-    """(target cfg, draft cfg, target weights, draft weights) — semantic
-    weights owned by the benchmark."""
-    from repro import configs
-    from repro.configs.base import ModelConfig
-    tc = ModelConfig(**model_cfg)
-    assert draft_rule["rule"] == "draft_variant", draft_rule
-    dc = configs.draft_variant(tc, draft_rule["scale"])
-    return tc, dc, weights_mod.make(tc, seed_t), weights_mod.make(dc, seed_d)
-
-
 class ServedRun:
     """One run of a cell: build, warm, serve through the window, drain."""
 
-    def __init__(self, cell: dict, traffic: dict, tc, dc, tw, dw, seed: int,
+    def __init__(self, cell: dict, traffic: dict, target, draft, seed: int,
                  seconds: float, clock, trace_dir: Optional[str] = None,
-                 trace_seconds: float = 0.0, fault=None):
+                 trace_seconds: float = 0.0, fault=None, obs=None):
         from repro.core import EdgeCloudEngine, EngineConfig, MethodConfig
         from repro.core.channel import ChannelConfig
         from repro.serve import ServeConfig, ServeSession
-        self.cell, self.traffic, self.tc, self.dc = cell, traffic, tc, dc
+        self.cell, self.traffic = cell, traffic
+        self.target, self.draft = target, draft     # harness.kinds.Model
         # a traced run traces its whole window, which is then the
         # shorter of the two lengths: the profiler stops after the close
         self.seconds = min(seconds, trace_seconds) if trace_dir else seconds
@@ -113,8 +114,8 @@ class ServedRun:
         self.cache_len = (max(traffic["prompt_buckets"]) + traffic["out_max"]
                           + self.L + 8)
         self.engine = EdgeCloudEngine(
-            dc, weights_mod.to_program(dc, dw), tc,
-            weights_mod.to_program(tc, tw),
+            draft.cfg, draft.kind.to_program(draft.cfg, draft.w), target.cfg,
+            target.kind.to_program(target.cfg, target.w),
             MethodConfig(m["name"], K=m["K"], ell=m["ell"],
                          alpha=m["alpha"], eta=m["eta"]),
             EngineConfig(L_max=self.L, bit_budget=e["bit_budget"],
@@ -126,7 +127,9 @@ class ServedRun:
         self.session = ServeSession(self.engine, ServeConfig(
             max_batch=self.B, queue_cap=QUEUE_CAP,
             cache_len=self.cache_len, pipeline="pipelined",
-            speculate=True, n_cells=N_RADIO_CELLS))
+            speculate=True, n_cells=N_RADIO_CELLS), obs=obs)
+        self.obs = obs
+        self.obs_open = self.obs_close = None  # its counters' snapshots
         self.fault = fault
         # records
         self.phase = "setup"               # setup | settle | open | closed
@@ -151,6 +154,7 @@ class ServedRun:
         self.withdrawn = set()
         self._last_draft = None
         self._last_verify = None
+        self.programs: Dict[str, list] = {}  # module: [jit, abstract args]
         self._install_hooks()
 
     # ------------------------------------------------------------------
@@ -159,12 +163,16 @@ class ServedRun:
         edge_jit, cloud_jit = eng.edge._draft_jit, eng.cloud._verify_jit
 
         def draft_jit(dp, cache, x_in, pos_in, beta_in, keys):
-            cache, ys = edge_jit(dp, cache, x_in, pos_in, beta_in, keys)
+            args = (dp, cache, x_in, pos_in, beta_in, keys)
+            self._keep_program("jit__draft_round", edge_jit, args)
+            cache, ys = edge_jit(*args)
             self._last_draft = (ys, x_in, pos_in, beta_in)
             return cache, ys
 
         def verify_jit(tp, cache, tokens_in, pos, q_hat, live, key):
-            out = cloud_jit(tp, cache, tokens_in, pos, q_hat, live, key)
+            args = (tp, cache, tokens_in, pos, q_hat, live, key)
+            self._keep_program("jit__verify_round", cloud_jit, args)
+            out = cloud_jit(*args)
             if self.fault is not None:      # tests only: a broken step
                 out = self.fault(cache, out)
             self._last_verify = ((tokens_in, pos, q_hat, live, key), out)
@@ -251,6 +259,18 @@ class ServedRun:
         eng.verify_slots = w_verify
         eng.admit_slot = w_admit
 
+    # -- the step programs' compiled text (traced runs) ------------------
+    def _keep_program(self, module: str, jitted, args):
+        if self.trace_dir and module not in self.programs:
+            self.programs[module] = [jitted, jax.tree.map(_abstract, args)]
+
+    def program_texts(self) -> Dict[str, str]:
+        """Compiled HLO text of each step program, as it ran: lowered
+        again from the shapes of its first call, which finds the
+        executable already compiled in this process."""
+        return {m: f.lower(*args).compile().as_text()
+                for m, (f, args) in self.programs.items()}
+
     # -- audit ----------------------------------------------------------
     def _keep_row(self, kind: str, slot: int, arrays):
         req = self.slot_req.get(slot)
@@ -311,6 +331,8 @@ class ServedRun:
             self.link_open = self.link_counters()
             self.queue_open = len(self.session.topo.waiting)
             self.compiles_open = self.clock.n
+            if self.obs is not None:
+                self.obs_open = self.obs.metrics.snapshot()
             if self.trace_dir:
                 opts = jax.profiler.ProfileOptions()
                 opts.python_tracer_level = 0    # no per-function events
@@ -327,6 +349,8 @@ class ServedRun:
         self.link_close = self.link_counters()
         self.queue_close = len(self.session.topo.waiting)
         self.compiles_window = self.clock.n - self.compiles_open
+        if self.obs is not None:
+            self.obs_close = self.obs.metrics.snapshot()
         if self.trace_span:
             self.trace_span[1] = now
             jax.profiler.stop_trace()
@@ -355,12 +379,12 @@ class ServedRun:
         draft, verify, verdict application, the audit slicers."""
         eng, rng = self.engine, np.random.default_rng(0)
         for S in sorted(self.traffic["prompt_buckets"]):
-            eng.admit_slot(0, rng.integers(0, self.tc.vocab, S,
+            eng.admit_slot(0, rng.integers(0, self.target.cfg.vocab, S,
                                            dtype=np.int32), seed=1)
             eng.release_slot(0)
         S = min(self.traffic["prompt_buckets"])
         for slot in (0, self.B - 1):
-            eng.admit_slot(slot, rng.integers(0, self.tc.vocab, S,
+            eng.admit_slot(slot, rng.integers(0, self.target.cfg.vocab, S,
                                               dtype=np.int32), seed=2)
             rec = eng.draft_slots([slot])[slot]
             ys, x_in, pos_in, beta_in = self._last_draft

@@ -20,7 +20,7 @@ TINY = {"name": "tiny", "family": "dense", "n_layers": 2, "d_model": 128,
 def tiny_spec(method="csqs", slots=3, seconds_trace=1.0):
     return {
         "name": "tiny", "chips": 1,
-        "config": {"model": dict(TINY),
+        "config": {"name": "tiny", "kind": "dense", "model": dict(TINY),
                    "draft": {"rule": "draft_variant", "scale": 2}},
         "traffic": {"prompt_buckets": [8, 16], "prompt_weights": [0.5, 0.5],
                     "out_median": 6, "out_sigma": 0.5, "out_min": 3,

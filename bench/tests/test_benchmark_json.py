@@ -76,3 +76,22 @@ def test_config_files_list_their_cuts():
         assert conf["source"] == c["source"]
         for k, v in conf["reduced"].items():
             assert conf[k] == v["held"] != v["published"]
+
+
+def test_each_pair_of_config_and_traffic_once():
+    pairs = [(w["config"], w["traffic"]) for w in B["workloads"]]
+    assert len(pairs) == len(set(pairs)), pairs
+    assert len({w["name"] for w in B["workloads"]}) == len(B["workloads"])
+    assert {w["config"] for w in B["workloads"]} == {c["name"]
+                                                     for c in B["configs"]}
+
+
+def test_renamed_traffic_is_the_same_mix():
+    """``chat-ksqs`` is ``chat`` under a name of its own: the same
+    parameters, so a seed draws the same arrivals in both."""
+    def load(name):
+        with open(os.path.join(BENCH, "traffic", name + ".json")) as f:
+            t = json.load(f)
+        t.pop("knee_how")
+        return t
+    assert load("chat-ksqs") == load("chat")

@@ -1,7 +1,7 @@
 """The harness's own arithmetic, on the CPU in seconds: trace reduction
-on a synthetic trace with known intervals, FLOP and byte counts against
-hand-computed values for both configurations, the metric readers on
-recorded deliveries, and the traffic generator."""
+on a synthetic trace with known intervals, the dense kind's FLOP and
+byte counts against hand-computed values for both configurations, the
+metric readers on recorded deliveries, and the traffic generator."""
 import json
 import os
 import types
@@ -10,7 +10,9 @@ import numpy as np
 import pytest
 
 from conftest import BENCH
-from harness import cell, check, counts, traffic, tracefile
+from harness import cell, check, counts, kinds, traffic, tracefile
+
+dense = kinds.load("dense")
 
 
 def _cfg(name):
@@ -59,33 +61,33 @@ def test_module_name():
 def test_counts_qwen_by_hand():
     c = _cfg("qwen2.5-3b")
     # 2048*16*128*2 + 2048*2*128*2 + 3*2048*11008
-    assert counts.layer_matmul_params(c) == 77_070_336
-    assert counts.matmul_params_per_token(c) == 77_070_336 * 36 + 2048 * 151936
+    assert dense.layer_matmul_params(c) == 77_070_336
+    assert dense.matmul_params_per_token(c) == 77_070_336 * 36 + 2048 * 151936
     # layers + norms + QKV bias, final norm, tied head/embedding, bf16
-    assert counts.weight_bytes(c) == 2 * ((77_070_336 + 4096 + 2560) * 36
+    assert dense.weight_bytes(c) == 2 * ((77_070_336 + 4096 + 2560) * 36
                                           + 2048 + 2048 * 151936)
-    assert counts.kv_bytes_per_token(c) == 36 * 2 * 2 * 128 * 2
-    fl, by = counts.verify_call(c, [100], 8)
+    assert dense.kv_bytes_per_token(c) == 36 * 2 * 2 * 128 * 2
+    fl, by = dense.verify_call(c, [100], 8)
     attn = 36 * 4 * 16 * 128 * sum(100 + i + 1 for i in range(9))
-    assert fl == 2 * counts.matmul_params_per_token(c) * 9 + attn
-    assert by == (counts.weight_bytes(c) + 100 * 36864 + 9 * 36864
+    assert fl == 2 * dense.matmul_params_per_token(c) * 9 + attn
+    assert by == (dense.weight_bytes(c) + 100 * 36864 + 9 * 36864
                   + 9 * 151936 * 4)
 
 
 def test_counts_deepseek_by_hand():
     c = _cfg("deepseek-7b-l12")
-    assert counts.layer_matmul_params(c) == 202_375_168
-    assert counts.kv_bytes_per_token(c) == 196_608
+    assert dense.layer_matmul_params(c) == 202_375_168
+    assert dense.kv_bytes_per_token(c) == 196_608
     # untied: the head is read, the embedding table is only gathered
-    assert counts.weight_bytes(c) == 2 * ((202_375_168 + 8192) * 12 + 4096
+    assert dense.weight_bytes(c) == 2 * ((202_375_168 + 8192) * 12 + 4096
                                           + 4096 * 102400)
-    fl, by = counts.prefill_call(c, 3)
+    fl, by = dense.prefill_call(c, 3)
     n = 2
     assert fl == (2 * 12 * 202_375_168 * n + 2 * 4096 * 102400
                   + 12 * 4 * 32 * 128 * (1 + 2))
-    assert by == counts.weight_bytes(c) + n * 196_608
-    fl, by = counts.draft_call(c, [10, 20], 1)
-    assert by == (2 * counts.weight_bytes(c)
+    assert by == dense.weight_bytes(c) + n * 196_608
+    fl, by = dense.draft_call(c, [10, 20], 1)
+    assert by == (2 * dense.weight_bytes(c)
                   + (10 + 11 + 20 + 21) * 196_608 + 2 * 2 * 196_608
                   + 2 * 2 * 102400 * 4 * 2)
 
@@ -113,6 +115,19 @@ def _rec():
     r.calls = [("draft", 10.1, 10.2, 0.05, (0,), (10,)),
                ("verify", 10.2, 10.3, 0.02, (0, 1), (10, 12)),
                ("verify", 10.4, 10.5, 0.04, (1,), (13,))]
+    # the program's readings of a traced run: (name, t0, t1, id, parent,
+    # args) spans in the window, counter increments, scope seconds
+    r.spans = [("edge.pack", 10.1, 10.12, 0, -1, {}),
+               ("cloud.unpack", 10.2, 10.21, 1, -1, {}),
+               ("edge.fetch", 10.3, 10.34, 2, -1, {}),
+               ("cloud.upload", 10.4, 10.41, 3, -1, {}),
+               ("edge.step", 10.5, 10.9, 4, -1, {})]
+    r.counters = {"wire.support_tokens": 300, "wire.live_drafts": 10,
+                  "wire.payloads": 4, "edge.rows_requested": 3,
+                  "edge.rows_computed": 12}
+    r.scope_dev_s = {"jit__draft_round": {"sqs": 0.016, "slm": 0.03},
+                     "jit__verify_round": {"llm": 0.02}}
+    r.trace = {"programs": {"jit__draft_round": {"n": 2, "dev_s": 0.05}}}
     return r
 
 
@@ -127,6 +142,12 @@ def _rec():
     ("draft_step_ms", 50.0),
     ("verify_step_ms", 30.0),
     ("offclock_share", (1 - 0.11 / 2.0) * 100),
+    ("codec_share", (0.02 + 0.01) / 2.0 * 100),
+    ("handoff_share", (0.04 + 0.01) / 2.0 * 100),
+    ("sqs_step_ms", 0.016 / 2 * 1e3),
+    ("support_k_mean", 30.0),
+    ("draft_len_mean", 2.5),
+    ("draft_row_share", 25.0),
 ])
 def test_metric_readers(name, want):
     assert cell.reader(name)(_rec()) == pytest.approx(want)
@@ -135,10 +156,27 @@ def test_metric_readers(name, want):
 def test_readers_find_nothing_to_read():
     r = _rec()
     r.deliveries, r.calls, r.trace = [], [], None
+    # an untraced run has no program readings
+    r.spans = r.counters = r.scope_dev_s = None
     for name in ("out_tok_s", "itl_p95_ms", "served_itl_p95_ms",
                  "served_tpot_ms", "slots_per_verify",
-                 "draft_step_ms", "idle_share"):
+                 "draft_step_ms", "idle_share", "codec_share",
+                 "handoff_share", "sqs_step_ms", "support_k_mean",
+                 "draft_len_mean", "draft_row_share"):
         assert cell.reader(name)(r) is None
+
+
+@pytest.mark.parametrize("name", ["codec_share", "handoff_share",
+                                  "sqs_step_ms", "support_k_mean",
+                                  "draft_len_mean", "draft_row_share"])
+def test_program_readers_find_nothing_in_a_program_without_them(name):
+    """A traced run of a program that records none of these spans,
+    counters or scopes: each reader gives None, never 0."""
+    r = _rec()
+    r.spans = [("edge.step", 10.5, 10.9, 4, -1, {})]
+    r.counters = {"serve.drafts": 3}
+    r.scope_dev_s = {"jit__draft_round": {"(unscoped)": 0.04}}
+    assert cell.reader(name)(r) is None
 
 
 # -- traffic ----------------------------------------------------------------
@@ -201,7 +239,7 @@ def test_logit_gap_and_its_rest_outside_the_head():
     rng = np.random.default_rng(0)
     V, d, T = 600, 24, 0.2
     w = {"embed": rng.standard_normal((V, d)).astype(np.float32) / d ** 0.5}
-    space = check.HeadSpace(w)
+    space = check.HeadSpace(w["embed"])
     z = rng.standard_normal((3, d)) @ w["embed"].T.astype(np.float64)
     # an error of the hidden state: a gap, and nothing outside the head
     dz = rng.standard_normal((3, d)) @ w["embed"].T.astype(np.float64) * 0.01

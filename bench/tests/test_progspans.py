@@ -1,72 +1,108 @@
-"""The reductions of the program's own spans and counters
-(``harness.progspans``): idle attribution and named-scope device time
-on a synthetic trace, each reader on recorded spans and counters, and
-on one small CPU served run the program's counters against the
-benchmark's own ``slots_per_verify`` and ``tok_per_slot_round``."""
+"""The program's own readings: idle attribution and named-scope device
+time of both step programs on a synthetic trace (``harness.tracefile``),
+``Records`` carrying them, each span and counter reader
+(``harness.progspans``), and on small CPU served runs the program's
+counters against the benchmark's own ``slots_per_verify`` and
+``tok_per_slot_round``, and a whole traced run reporting the six
+readings that exist on the CPU."""
 import time
+import types
 
+import jax
 import pytest
 
 from conftest import tiny_spec
-from harness import cell, progspans, session, traffic
+from harness import cell, kinds, progspans, session, tracefile, traffic
 from harness.clocks import CompileClock
 
 D, H = "/device:TPU:0", "/host:CPU"
-SQS = {"tf_op": "jit(_draft_round)/while/body/sqs/sort"}
 
 
 def _trace():
     return [
-        (D, "XLA Modules", "jit__draft_round(3)", 0, 110, {}),
-        (D, "XLA Ops", "while.5", 0, 100, {}),          # container
-        (D, "XLA Ops", "sort.9", 10, 30, SQS),          # scope from stats
-        (D, "XLA Ops", "fusion.3", 50, 40, {}),         # scope from HLO text
-        (D, "XLA Ops", "copy.4", 100, 10, {}),          # under no scope
-        (D, "XLA Modules", "jit__verify_round(4)", 400, 20, {}),
-        (D, "XLA Ops", "fusion.1", 400, 20, {}),
-        (D, "XLA Ops", "fusion.2", 700, 10, {}),
-        (H, "python", "bench.draft", 100, 550, {}),
-        (H, "python", "edge.fetch", 200, 100, {}),      # nested in bench.*
-        (H, "python", "edge.pack", 500, 50, {}),
+        (D, "XLA Modules", "jit__draft_round(3)", 0, 110),
+        (D, "XLA Ops", "while.5", 0, 100),          # container
+        (D, "XLA Ops", "sort.9", 10, 30),           # sqs
+        (D, "XLA Ops", "fusion.3", 50, 40),         # slm
+        (D, "XLA Ops", "copy.4", 100, 10),          # under no scope
+        (D, "XLA Modules", "jit__verify_round(4)", 400, 30),
+        (D, "XLA Ops", "fusion.1", 400, 20),        # llm
+        (D, "XLA Ops", "%fusion.2 = f32[4] fusion(%a)", 420, 10),  # verify
+        (D, "XLA Ops", "fusion.7", 700, 10),        # in no program
+        (H, "python", "bench.draft", 100, 550),
+        (H, "python", "edge.fetch", 200, 100),      # nested in bench.*
+        (H, "python", "edge.pack", 500, 50),
     ]
 
 
+DRAFT_HLO = """
+  %sort.9 = f32[4,1024]{1,0} sort(%p), metadata={op_name="jit(_draft_round)/while/body/sqs/sort" stack_frame_id=2}
+  %fusion.3 = bf16[4,1024]{1,0} fusion(%p), kind=kLoop, calls=%c, metadata={op_name="jit(_draft_round)/while/body/closed_call/slm/dot_general" stack_frame_id=3}
+  ROOT %copy.4 = f32[4]{0} copy(%x)
+"""
+VERIFY_HLO = """
+  %fusion.1 = bf16[4,1024]{1,0} fusion(%p), kind=kLoop, metadata={op_name="jit(_verify_round)/llm/dot_general"}
+  ROOT %fusion.2 = f32[4]{0} fusion(%a), kind=kLoop, metadata={op_name="jit(_verify_round)/verify/reduce_max"}
+"""
+
+
+def _names():
+    return {"jit__draft_round": tracefile.hlo_op_names(DRAFT_HLO),
+            "jit__verify_round": tracefile.hlo_op_names(VERIFY_HLO)}
+
+
 def test_idle_by_span_prefers_the_program_span():
-    idle = dict(progspans.idle_by_span(_trace()))
+    idle = dict(tracefile.reduce(_trace(), (0, 1))["idle_gaps"])
     # gap 110-400 (midpoint 255): inside edge.fetch inside bench.draft
     assert idle["edge.fetch"] == pytest.approx(290e-9)
-    # gap 420-700 (midpoint 560): past edge.pack, still in bench.draft
-    assert idle["bench.draft"] == pytest.approx(280e-9)
+    # gap 430-700 (midpoint 565): past edge.pack, still in bench.draft
+    assert idle["bench.draft"] == pytest.approx(270e-9)
     assert set(idle) == {"edge.fetch", "bench.draft"}
 
 
 def test_idle_by_span_falls_back_to_the_host():
-    ev = [(D, "XLA Ops", "a", 0, 10, {}), (D, "XLA Ops", "b", 30, 10, {})]
-    assert progspans.idle_by_span(ev) == [[progspans.OTHER_HOST, 20e-9]]
-
-
-HLO = """
-  %fusion.3 = bf16[4,1024]{1,0} fusion(%p), kind=kLoop, calls=%c, metadata={op_name="jit(_draft_round)/while/body/closed_call/slm/dot_general" stack_frame_id=3}
-  ROOT %copy.4 = f32[4]{0} copy(%x)
-"""
+    ev = [(D, "XLA Ops", "a", 0, 10), (D, "XLA Ops", "b", 30, 10)]
+    assert tracefile.reduce(ev, (0, 1))["idle_gaps"] == \
+        [[tracefile.OTHER_HOST, 20e-9]]
 
 
 def test_hlo_op_names_and_scope_of():
-    names = progspans.hlo_op_names(HLO)
-    assert names == {"fusion.3": "jit(_draft_round)/while/body/"
-                                 "closed_call/slm/dot_general"}
-    assert progspans.scope_of("jit(f)/while/body/slm/sqs/jit(sort)/sort") \
+    names = tracefile.hlo_op_names(DRAFT_HLO)
+    assert names == {
+        "sort.9": "jit(_draft_round)/while/body/sqs/sort",
+        "fusion.3": "jit(_draft_round)/while/body/closed_call/slm/"
+                    "dot_general"}
+    assert tracefile.scope_of("jit(f)/while/body/slm/sqs/jit(sort)/sort") \
         == "sqs"
-    assert progspans.scope_of("jit(f)/copy") == progspans.UNSCOPED
+    assert tracefile.scope_of("jit(f)/copy") == tracefile.UNSCOPED
 
 
 def test_scope_dev_s_counts_leaves_once():
-    sc = progspans.scope_dev_s(_trace(), op_names=progspans.hlo_op_names(HLO))
-    assert sc["sqs"] == pytest.approx(30e-9)
-    assert sc["slm"] == pytest.approx(40e-9)
-    assert sc[progspans.UNSCOPED] == pytest.approx(10e-9)
-    assert sc["_leaf"] == pytest.approx(80e-9)       # the while not again
-    assert progspans.scope_dev_s(_trace(), module="jit__nothing") == {}
+    sc = tracefile.reduce(_trace(), (0, 1), _names())["scope_dev_s"]
+    d, v = sc["jit__draft_round"], sc["jit__verify_round"]
+    assert d["sqs"] == pytest.approx(30e-9)
+    assert d["slm"] == pytest.approx(40e-9)
+    assert d[tracefile.UNSCOPED] == pytest.approx(10e-9)
+    assert d["_leaf"] == pytest.approx(80e-9)       # the while not again
+    assert v == pytest.approx({"llm": 20e-9, "verify": 10e-9,
+                               "_leaf": 30e-9})
+    # a program given no compiled text, or that did not run, has none
+    assert set(tracefile.reduce(_trace(), (0, 1))["scope_dev_s"]) == set()
+    assert "jit__nothing" not in tracefile.reduce(
+        _trace(), (0, 1), {"jit__nothing": {}})["scope_dev_s"]
+
+
+def test_records_carry_both_programs_scope_times():
+    run = types.SimpleNamespace(
+        target=None, draft=None, L=4, t_open=0.0, t_close=1e-6,
+        now_open=0.0, now_close=1.0, deliveries=[], calls=[],
+        admissions=[], link_open=(0, 0), link_close=(0, 0), obs=None)
+    red = tracefile.reduce(_trace(), (0.0, 1e-6), _names())
+    rec = cell.Records(run, {"cell": {}, "traffic": {}}, 1.0, red, None)
+    assert rec.scope_dev_s["jit__draft_round"]["sqs"] == pytest.approx(30e-9)
+    assert rec.scope_dev_s["jit__verify_round"]["llm"] == pytest.approx(20e-9)
+    assert rec.spans is None and rec.counters is None
+    assert cell.reader("sqs_step_ms")(rec) == pytest.approx(30e-9 * 1e3)
 
 
 def _spans():
@@ -90,6 +126,7 @@ def test_span_readers():
     assert progspans.codec_share(spans, 1.0) == pytest.approx(2.5)
     assert progspans.handoff_share(spans, 1.0) == pytest.approx(2.5)
     assert progspans.codec_share([], 1.0) is None
+    assert progspans.codec_share(None, 1.0) is None
     calls = [("draft", 10.0, 10.11, 0.08), ("verify", 10.2, 10.25, 0.02)]
     # the draft call's 0.03 s off the step: 0.02 covered; verify's 0.03: all
     assert progspans.coverage(spans, calls) == pytest.approx(0.05 / 0.06)
@@ -130,38 +167,59 @@ def test_readers_find_nothing_to_read():
               progspans.draft_row_share, progspans.tok_per_slot_round,
               progspans.slots_per_verify):
         assert f(d) is None
+        assert f(None) is None
     assert progspans.sqs_step_ms({}, {}) is None
+    assert progspans.sqs_step_ms(None, {"jit__draft_round": {"n": 2}}) is None
     assert progspans.coverage([], []) is None
 
 
 def test_program_counters_reproduce_the_benchmarks_readers():
-    """On one small CPU served run with the program's Obs attached, its
-    own counters give the benchmark's slots_per_verify and
-    tok_per_slot_round over the same window."""
+    """On one small CPU served run with the program's Obs handed to its
+    ServeSession, its own counters give the benchmark's slots_per_verify
+    and tok_per_slot_round over the same window."""
     from repro.obs import Obs
     seed = 20240612
     spec = tiny_spec(slots=3)
-    tc, dc, tw, dw = session.build_models(spec["config"]["model"],
-                                          spec["config"]["draft"], 11, 12)
-    run = session.ServedRun(spec["cell"], spec["traffic"], tc, dc, tw, dw,
-                            seed, 2.0, CompileClock())
-    obs = Obs.on()
-    progspans.attach(run, obs)
+    target, draft = kinds.build(spec["config"], 11, 12)
+    run = session.ServedRun(spec["cell"], spec["traffic"], target, draft,
+                            seed, 2.0, CompileClock(), obs=Obs.on())
     run.warm()
-    run.run(traffic.make_trace(spec["traffic"], tc.vocab, seed,
+    run.run(traffic.make_trace(spec["traffic"], target.cfg.vocab, seed,
                                spec["traffic"]["n_requests"]))
     rec = cell.Records(run, spec, time.perf_counter(), None, None)
-    d = progspans.deltas(run.obs_snap["open"], run.obs_snap["closed"])
+    d = rec.counters
     assert progspans.slots_per_verify(d) == pytest.approx(
         cell.reader("slots_per_verify")(rec))
     assert progspans.tok_per_slot_round(d) == pytest.approx(
         cell.reader("tok_per_slot_round")(rec))
-    spans = progspans.in_window(obs.tracer.wall_spans(), rec.t_open,
-                                rec.t_close)
     assert {"edge.step", "edge.fetch", "edge.pack", "cloud.step",
-            "cloud.upload"} <= {s[0] for s in spans}
-    assert 0 < progspans.codec_share(spans, rec.window_s) < 100
+            "cloud.upload"} <= {s[0] for s in rec.spans}
+    assert 0 < progspans.codec_share(rec.spans, rec.window_s) < 100
     assert progspans.draft_row_share(d) == pytest.approx(100 / 3)
     assert progspans.support_k_mean(d) > 0
     assert 1 <= progspans.draft_len_mean(d) <= spec["cell"]["engine"]["L_max"]
-    assert progspans.coverage(spans, rec.calls) > 0.5
+    assert progspans.coverage(rec.spans, rec.calls) > 0.5
+
+
+SIX = ("codec_share", "handoff_share", "sqs_step_ms", "support_k_mean",
+       "draft_len_mean", "draft_row_share")
+
+
+def test_a_traced_run_reports_the_program_readings():
+    """The whole traced path on the CPU: the Obs reaches the program,
+    Records carry its spans and counters, both step programs' compiled
+    text is read, and the per-layer line has every reading that exists
+    here.  ``sqs_step_ms`` reads device ops, and the CPU has no TPU
+    plane: it is left out, not 0."""
+    spec = tiny_spec(slots=3)
+    spec["per_layer"] = [{"name": n, "unit": "x"} for n in SIX]
+    res, rows = cell.run(spec, 20240613, 60.0, True, time.perf_counter(),
+                         jax.devices())
+    assert res["correct"], rows
+    got = res["metrics"]
+    assert set(got) == set(SIX) - {"sqs_step_ms"}, got
+    assert 0 < got["codec_share"]["value"] < 100
+    assert 0 < got["handoff_share"]["value"] < 100
+    assert got["draft_row_share"]["value"] == pytest.approx(100 / 3)
+    assert res["device"]["window_s"] > 0
+    assert "idle_gaps" in res["breakdown"]
